@@ -33,6 +33,9 @@ import sys
 
 
 def main() -> int:
+    # standalone `python scripts/bench_spmd.py` finds the package too
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     smoke = os.environ.get("BENCH_SMOKE") == "1"
     if smoke or os.environ.get("JAX_PLATFORMS") == "cpu":
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -45,6 +48,10 @@ def main() -> int:
 
     import jax
     import numpy as np
+
+    from sitewhere_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
 
     from sitewhere_tpu.core.events import EpochBase
     from sitewhere_tpu.engine import Engine, EngineConfig

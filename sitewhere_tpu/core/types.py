@@ -1,6 +1,6 @@
 """Core type constants for the TPU-native event engine.
 
-The six device-event classes mirror the reference's event taxonomy
+The six device-event classes mirror the reference's event classes
 (reference: service-event-management/.../kafka/EventPersistenceMapper.java:92-115,
 which dispatches addDeviceMeasurements / addDeviceLocations / addDeviceAlerts /
 addDeviceCommandInvocations / addDeviceCommandResponses / addDeviceStateChanges).

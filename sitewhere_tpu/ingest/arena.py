@@ -6,7 +6,7 @@ arrays, ``_ingest_decoded`` copies the accepted rows into the
 ``HostEventBuffer``, and ``emit()`` re-allocates the buffer for the next
 batch. On a 1-core driver those copies and allocations are a large slice
 of the ~30x gap between the fused device step and the host e2e rate
-(ISSUE 2 / BENCH_r05).
+(ISSUE 2; measured before PR 2 on a runtime that is gone).
 
 A :class:`StagingArena` is ONE preallocated SoA buffer holding both the
 decoder's scratch columns (``rtype``/``ts64``/``level``) and the final

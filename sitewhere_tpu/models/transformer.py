@@ -205,9 +205,7 @@ def forecast_scores_sp(
     """Sequence-parallel anomaly scoring of [B, S, C] windows with S sharded
     over ``axis``. Numerically equals ``forecast_scores`` on one device."""
     s = x.shape[1]
-    from sitewhere_tpu.compat import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_sp_scores_local, cfg=cfg, axis=axis, total_len=s),
         mesh=mesh,
         in_specs=(P(), P(None, axis, None)),

@@ -1,17 +1,20 @@
 """ctypes binding + on-demand build of the native host data-plane (swtpu).
 
 Builds native/src/swtpu.cpp with g++ -O3 on first use (cached in
-native/build/). Falls back cleanly: ``load_library()`` returns None when no
-compiler is available, and callers (ingest/fast_decode.py, engine interners)
+native/build/ under a key of sources, command, compiler and host CPU).
+Falls back cleanly: ``load_library()`` returns None when no compiler is
+available, and callers (ingest/fast_decode.py, engine interners)
 use the pure-Python path.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import pathlib
+import platform
 import subprocess
 import threading
 
@@ -21,8 +24,6 @@ _REPO = pathlib.Path(__file__).resolve().parents[2]
 _SRC = _REPO / "native" / "src" / "swtpu.cpp"
 _PY_SRC = _REPO / "native" / "src" / "swtpu_py.cpp"
 _BUILD = _REPO / "native" / "build"
-_SO = _BUILD / "libswtpu.so"
-_PY_SO = _BUILD / "libswtpu_py.so"
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -113,53 +114,79 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
     return lib
 
 
-def build_library(force: bool = False) -> pathlib.Path | None:
-    """Compile the shared library (cached by source mtime). The link
-    writes a temp file that RENAMES over the target: a process that
-    already dlopen'd the old .so keeps its mapping of the old inode —
-    linking in place would truncate pages out from under it."""
-    _BUILD.mkdir(parents=True, exist_ok=True)
-    if _SO.exists() and not force and _SO.stat().st_mtime >= _SRC.stat().st_mtime:
-        return _SO
-    tmp = _SO.with_suffix(f".tmp{os.getpid()}.so")
-    cmd = ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
-           str(_SRC), "-o", str(tmp)]
+def _host_cpu() -> str:
+    """What ``-march=native`` resolves from: the CPU's vendor, model and
+    feature flags (first core of /proc/cpuinfo)."""
+    seen: dict[str, str] = {}
     try:
-        subprocess.run(cmd, check=True, capture_output=True, text=True)
-        tmp.rename(_SO)
-    except (subprocess.CalledProcessError, FileNotFoundError, OSError) as e:
-        logger.warning("native build failed (%s); using Python fallback",
-                       getattr(e, "stderr", e))
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                key, _, val = line.partition(":")
+                key = key.strip()
+                if key in ("vendor_id", "model name", "flags", "Features",
+                           "CPU implementer", "CPU part"):
+                    seen.setdefault(key, val.strip())
+    except OSError:
+        pass
+    return f"{platform.machine()}|{sorted(seen.items())}"
+
+
+def _build(stem: str, cmd: list[str], fail_level: int):
+    """Compile ``cmd`` into ``native/build/<stem>-<key>.so``. The key hashes
+    every source under native/src, the command, the compiler's version and
+    the host CPU, so a library built from other sources, by another
+    compiler or for another CPU is never loaded: it is rebuilt. The link
+    writes a temp file that RENAMES over the target: a process that
+    already dlopen'd an older build keeps its mapping of that inode."""
+    h = hashlib.sha256()
+    for src in sorted(_SRC.parent.iterdir()):
+        if src.is_file():
+            h.update(src.name.encode() + b"\0" + src.read_bytes())
+    h.update("\0".join(cmd).encode())
+    try:
+        h.update(subprocess.run([cmd[0], "--version"], capture_output=True,
+                                check=True).stdout)
+    except (subprocess.CalledProcessError, OSError) as e:
+        logger.log(fail_level, "native build unavailable (%s); "
+                   "using Python fallback", e)
+        return None
+    h.update(_host_cpu().encode())
+    so = _BUILD / f"{stem}-{h.hexdigest()[:16]}.so"
+    if so.exists():
+        return so
+    _BUILD.mkdir(parents=True, exist_ok=True)
+    tmp = so.with_suffix(f".tmp{os.getpid()}.so")
+    try:
+        subprocess.run(cmd + ["-o", str(tmp)], check=True,
+                       capture_output=True, text=True)
+        tmp.rename(so)
+    except (subprocess.CalledProcessError, OSError) as e:
+        logger.log(fail_level, "native build of %s failed (%s); using "
+                   "Python fallback", stem, getattr(e, "stderr", e))
         tmp.unlink(missing_ok=True)
         return None
-    return _SO
+    return so
 
 
-def build_py_library(force: bool = False) -> pathlib.Path | None:
-    """Compile the CPython-aware variant (list[bytes] decode entry point;
+def build_library() -> pathlib.Path | None:
+    """Build (or find the matching build of) the shared library."""
+    return _build("libswtpu", ["g++", "-O3", "-march=native", "-shared",
+                               "-fPIC", "-std=c++17", str(_SRC)],
+                  logging.WARNING)
+
+
+def build_py_library() -> pathlib.Path | None:
+    """Build the CPython-aware variant (list[bytes] decode entry point;
     native/src/swtpu_py.cpp). Optional: failure only loses the
     zero-copy path, never the base library."""
     import sysconfig
 
     if not _PY_SRC.exists():
         return None
-    _BUILD.mkdir(parents=True, exist_ok=True)
-    newest = max(_SRC.stat().st_mtime, _PY_SRC.stat().st_mtime)
-    if _PY_SO.exists() and not force and _PY_SO.stat().st_mtime >= newest:
-        return _PY_SO
-    tmp = _PY_SO.with_suffix(f".tmp{os.getpid()}.so")
-    cmd = ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
-           f"-I{sysconfig.get_path('include')}",
-           f"-I{_SRC.parent}", str(_PY_SRC), "-o", str(tmp)]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, text=True)
-        tmp.rename(_PY_SO)
-    except (subprocess.CalledProcessError, FileNotFoundError, OSError) as e:
-        logger.info("py-bridge build failed (%s); packed path only",
-                    getattr(e, "stderr", e))
-        tmp.unlink(missing_ok=True)
-        return None
-    return _PY_SO
+    return _build("libswtpu_py", [
+        "g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
+        f"-I{sysconfig.get_path('include')}", f"-I{_SRC.parent}",
+        str(_PY_SRC)], logging.INFO)
 
 
 def load_library() -> ctypes.CDLL | None:

@@ -19,8 +19,8 @@ TPU mapping:
   * grid = (batch*heads, q-blocks, k-blocks) with the k axis innermost and
     sequential ("arbitrary"), accumulating into VMEM scratch.
 
-The jnp reference is the oracle for tests and the fallback on non-TPU
-backends (interpret mode covers the kernel itself in CI).
+The jnp reference is the oracle for tests and what runs on the CPU
+backend (interpret mode covers the kernel itself in CI).
 """
 
 from __future__ import annotations
@@ -31,8 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from sitewhere_tpu import compat as _compat
 
 _NEG_INF = -1e30
 
@@ -124,7 +122,7 @@ def _pick_block(s: int, preferred: int) -> int:
 
 @functools.partial(
     jax.jit, static_argnames=("causal", "sm_scale", "block_q", "block_k",
-                              "force_pallas")
+                              "interpret")
 )
 def flash_attention(
     q: jax.Array,
@@ -135,18 +133,34 @@ def flash_attention(
     sm_scale: float | None = None,
     block_q: int = 512,
     block_k: int = 512,
-    force_pallas: bool = False,
+    interpret: bool = False,
 ) -> jax.Array:
     """Blockwise attention, [B, S, H, D] -> [B, S, H, D].
 
-    Runs the Pallas kernel on TPU (interpret mode when forced on CPU for
-    tests); jnp oracle elsewhere. D is padded to a lane-friendly multiple of
-    128 inside the kernel and sliced back.
+    The jnp oracle on a CPU backend; the Pallas kernel everywhere else,
+    where a compile error raises. ``interpret=True`` runs the kernel in
+    interpret mode on any backend (the CPU tests).
     """
-    on_tpu = jax.default_backend() == "tpu"
-    if not (on_tpu or force_pallas):
+    if jax.default_backend() == "cpu" and not interpret:
         return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale)
+    return flash_attention_pallas(q, k, v, causal=causal, sm_scale=sm_scale,
+                                  block_q=block_q, block_k=block_k,
+                                  interpret=interpret)
 
+
+def flash_attention_pallas(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    *,
+    causal: bool = False,
+    sm_scale: float | None = None,
+    block_q: int = 512,
+    block_k: int = 512,
+    interpret: bool = False,
+) -> jax.Array:
+    """The Pallas kernel behind :func:`flash_attention`. D is padded to a
+    lane-friendly multiple of 128 inside the kernel and sliced back."""
     b, s, h, d = q.shape
     scale = sm_scale if sm_scale is not None else 1.0 / float(d) ** 0.5
     bq = _pick_block(s, block_q)
@@ -189,10 +203,11 @@ def flash_attention(
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, dd), jnp.float32),
         ],
-        compiler_params=_compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=not on_tpu,
+        name="flash_attention",
+        interpret=interpret,
     )(qf, kf, vf)
 
     out = out.reshape(b, h, s, dd)[..., :d]

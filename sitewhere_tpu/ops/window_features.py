@@ -10,7 +10,7 @@ The Pallas kernel makes this ONE pass over HBM per tile (six reductions
 fused in VMEM, single read of the window data), where the naive jnp
 version materializes multiple reduction intermediates. The reference has
 no equivalent: it re-queries time-series DBs for any analysis. A jnp
-reference implementation is used on non-TPU backends and as the test
+reference implementation is used on the CPU backend and as the test
 oracle.
 
 Feature layout (axis -1): [mean, std, min, max, last, delta].
@@ -57,26 +57,38 @@ def _features_kernel(win_ref, out_ref):
     out_ref[:] = jnp.stack([mean, std, mn, mx, last, delta], axis=-1)
 
 
-@functools.partial(jax.jit, static_argnames=("tile_m", "force_pallas"))
-def window_features(windows: jax.Array, tile_m: int = 256,
-                    force_pallas: bool = False) -> jax.Array:
-    """[M, W, C] -> [M, C, NUM_FEATURES]. Uses the Pallas kernel on TPU
-    (or when forced, e.g. interpret-mode tests); jnp elsewhere."""
-    m, w, c = windows.shape
-    on_tpu = jax.default_backend() == "tpu"
-    if not (on_tpu or force_pallas):
-        return window_features_reference(windows)
+# Scoped VMEM a kernel may use on v5e: 16 MiB. Compiles for v5e (PR 21)
+# put the kernel's need at about nine padded [C, W] window tiles per row
+# of a grid step (input blocks double-buffered, the output block padded to
+# a 128-lane tile, the squares and reductions in between), so a row is
+# budgeted at ten.
+_VMEM_BUDGET = 16 * 2**20
 
+
+def _round_up(x: int, k: int) -> int:
+    return -(-x // k) * k
+
+
+def _tile_rows(m: int, c: int, w: int, tile_m: int) -> int:
+    """Rows per grid step: at most ``tile_m`` and what the VMEM budget
+    holds for [C, W] windows, a power of two so tiles divide M."""
+    per_row = 10 * 4 * _round_up(c, 8) * _round_up(w, 128)
+    fit = max(1, _VMEM_BUDGET // per_row)
+    return 1 << (min(tile_m, fit, m).bit_length() - 1)
+
+
+def window_features_pallas(windows: jax.Array, tile_m: int = 256,
+                           interpret: bool = False) -> jax.Array:
+    """The Pallas kernel, [M, W, C] -> [M, C, NUM_FEATURES], on any backend
+    that compiles it (``interpret=True`` runs it through the interpreter)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    tile = min(tile_m, m)
-    if m % tile:
-        pad = tile - m % tile
-        windows = jnp.pad(windows, ((0, pad), (0, 0), (0, 0)))
-        mp = m + pad
-    else:
-        mp = m
+    m, w, c = windows.shape
+    tile = _tile_rows(m, c, w, tile_m)
+    mp = _round_up(m, tile)
+    if mp != m:
+        windows = jnp.pad(windows, ((0, mp - m), (0, 0), (0, 0)))
     wt = jnp.swapaxes(windows.astype(jnp.float32), 1, 2)  # [M, C, W]
     out = pl.pallas_call(
         _features_kernel,
@@ -86,9 +98,22 @@ def window_features(windows: jax.Array, tile_m: int = 256,
         out_specs=pl.BlockSpec((tile, c, NUM_FEATURES), lambda i: (i, 0, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((mp, c, NUM_FEATURES), jnp.float32),
-        interpret=not on_tpu,
+        name="window_features",
+        interpret=interpret,
     )(wt)
     return out[:m]
+
+
+@functools.partial(jax.jit, static_argnames=("tile_m", "interpret"))
+def window_features(windows: jax.Array, tile_m: int = 256,
+                    interpret: bool = False) -> jax.Array:
+    """[M, W, C] -> [M, C, NUM_FEATURES]. The jnp reference on a CPU
+    backend; the Pallas kernel everywhere else, where a compile error
+    raises. ``interpret=True`` runs the kernel in interpret mode on any
+    backend (the CPU tests)."""
+    if jax.default_backend() == "cpu" and not interpret:
+        return window_features_reference(windows)
+    return window_features_pallas(windows, tile_m, interpret)
 
 
 # devicewatch (ISSUE 11): the analytics feature extractor (Pallas on
@@ -97,7 +122,7 @@ def window_features(windows: jax.Array, tile_m: int = 256,
 from sitewhere_tpu.utils.devicewatch import watched_jit  # noqa: E402
 
 window_features = watched_jit(window_features, family="window_features",
-                              static_argnames=("tile_m", "force_pallas"))
+                              static_argnames=("tile_m", "interpret"))
 
 
 def normalize_windows(windows: jax.Array, features: jax.Array,

@@ -31,9 +31,7 @@ def worker_main(rank: int, nproc: int, port: int,
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    from sitewhere_tpu.compat import set_cpu_device_count
-
-    set_cpu_device_count(devices_per_proc)
+    jax.config.update("jax_num_cpu_devices", devices_per_proc)
 
     from sitewhere_tpu.parallel import multihost
 

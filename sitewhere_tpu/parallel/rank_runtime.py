@@ -322,6 +322,9 @@ def run_rank(cfg: RankConfig) -> RankRuntime:
     ``cfg.snapshot_dir`` + the WAL when a snapshot exists there;
     validates the wiring BEFORE serving; returns a running
     ``RankRuntime``."""
+    from sitewhere_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     local = None
     recovered = False
     if cfg.snapshot_dir is not None and (

@@ -79,13 +79,12 @@ def ring_attention(
     # Initial accumulators are device-varying (they fold in shard-local
     # scores), so mark them varying along the mesh axis for shard_map's
     # manual-axes type system.
-    from sitewhere_tpu.compat import pcast
-
-    m = pcast(jnp.full((b, h, sq), _NEG_INF, jnp.float32), axis_name,
-              to="varying")
-    l = pcast(jnp.zeros((b, h, sq), jnp.float32), axis_name, to="varying")
-    acc = pcast(jnp.zeros((b, sq, h, d), jnp.float32), axis_name,
-                to="varying")
+    m = lax.pcast(jnp.full((b, h, sq), _NEG_INF, jnp.float32), axis_name,
+                  to="varying")
+    l = lax.pcast(jnp.zeros((b, h, sq), jnp.float32), axis_name,
+                  to="varying")
+    acc = lax.pcast(jnp.zeros((b, sq, h, d), jnp.float32), axis_name,
+                    to="varying")
     perm = [(j, (j + 1) % n) for j in range(n)]
 
     def body(t, carry):
@@ -145,9 +144,7 @@ def _sharded(
     **kw,
 ) -> jax.Array:
     spec = P(None, axis, None, None)
-    from sitewhere_tpu.compat import shard_map
-
-    mapped = shard_map(
+    mapped = jax.shard_map(
         functools.partial(fn, axis_name=axis, **kw),
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -110,9 +110,7 @@ def _sharded_step(
             jax.tree_util.tree_map(lambda x: x[None], out),
         )
 
-    from sitewhere_tpu.compat import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
@@ -430,8 +428,6 @@ def _make_spmd_step(mesh, config: PipelineConfig):
     the single-chip pipeline step over the stacked ``[S, ...]`` state and
     a stacked ``[S, B, ...]`` batch. Identical math per shard — the fused
     program IS ``pipeline_step``, once per chip, in one dispatch."""
-    from sitewhere_tpu.compat import shard_map
-
     def local_step(state_blk, batch_blk):
         lstate = jax.tree_util.tree_map(lambda x: x[0], state_blk)
         lbatch = jax.tree_util.tree_map(lambda x: x[0], batch_blk)
@@ -441,7 +437,7 @@ def _make_spmd_step(mesh, config: PipelineConfig):
             jax.tree_util.tree_map(lambda x: x[None], out),
         )
 
-    fused = shard_map(
+    fused = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
@@ -457,11 +453,9 @@ def _make_spmd_scan_step(mesh, config: PipelineConfig, capacity: int,
     ``[k * capacity]`` arena lane reshapes to ``[k, capacity]`` INSIDE the
     jitted program and consumes as one ``lax.scan`` — K single-chip steps
     per shard in ONE dispatch (one transfer group + one program launch,
-    the remote-chip amortizer, now fused across the mesh). Only the state
+    fused across the mesh). Only the state
     donates; the stacked batch rides in whole, exactly the single-chip
     ``make_arena_scan_step`` donation discipline."""
-    from sitewhere_tpu.compat import shard_map
-
     def local_step(state_blk, batch_blk):
         lstate = jax.tree_util.tree_map(lambda x: x[0], state_blk)
         lbatch = jax.tree_util.tree_map(lambda x: x[0], batch_blk)
@@ -477,7 +471,7 @@ def _make_spmd_scan_step(mesh, config: PipelineConfig, capacity: int,
             jax.tree_util.tree_map(lambda x: x[None], outs),
         )
 
-    fused = shard_map(
+    fused = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),

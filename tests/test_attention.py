@@ -46,7 +46,7 @@ def _qkv(rng, b=2, s=256, h=4, d=32):
 def test_flash_attention_matches_oracle(rng, causal):
     q, k, v = _qkv(rng)
     out = flash_attention(q, k, v, causal=causal, block_q=128, block_k=64,
-                          force_pallas=True)
+                          interpret=True)
     ref = mha_reference(q, k, v, causal=causal)
     np.testing.assert_allclose(out, ref, **TOL)
 
@@ -54,14 +54,14 @@ def test_flash_attention_matches_oracle(rng, causal):
 def test_flash_attention_lane_padding(rng):
     # D=32 pads to 128 lanes inside the kernel; result must be unchanged.
     q, k, v = _qkv(rng, s=64, h=2, d=32)
-    out = flash_attention(q, k, v, block_q=32, block_k=32, force_pallas=True)
+    out = flash_attention(q, k, v, block_q=32, block_k=32, interpret=True)
     np.testing.assert_allclose(out, mha_reference(q, k, v), **TOL)
 
 
 def test_flash_attention_odd_block_fallback(rng):
     # S=96 is not divisible by the preferred 512 block; picker must find one.
     q, k, v = _qkv(rng, s=96, h=2, d=64)
-    out = flash_attention(q, k, v, force_pallas=True)
+    out = flash_attention(q, k, v, interpret=True)
     np.testing.assert_allclose(out, mha_reference(q, k, v), **TOL)
 
 

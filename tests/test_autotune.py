@@ -76,7 +76,10 @@ def test_decide_hysteresis_dead_zone():
 
 # ---------------------------------------------------------- engine control
 def test_autotuner_adapts_from_flight_records():
-    eng = Engine(EngineConfig(**SMALL, autotune=True, autotune_interval=4))
+    # one decode worker, whatever the host's core count: with auto
+    # (os.cpu_count()) workers the tuner first sheds workers instead
+    eng = Engine(EngineConfig(**SMALL, autotune=True, autotune_interval=4,
+                              ingest_workers=1))
     assert eng._autotuner is not None
     for b in range(16):
         eng.ingest_json_batch([

@@ -118,7 +118,7 @@ def test_window_features_pallas_matches_reference(rng):
 
     x = jnp.asarray(rng.standard_normal((100, 16, 8)), jnp.float32)
     ref = window_features_reference(x)
-    pal = window_features(x, tile_m=32, force_pallas=True)
+    pal = window_features(x, tile_m=32, interpret=True)
     np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
     normed = normalize_windows(x, ref)

@@ -11,20 +11,9 @@ file only spawns and checks them, so the in-process CPU-mesh conftest
 fixture is untouched.
 """
 
-import jax
-import pytest
-
 from sitewhere_tpu.parallel.multihost_demo import spawn_two_process_demo
 
-# jax 0.4.x CPU backend: "Multiprocess computations aren't implemented on
-# the CPU backend" — the cross-process CPU collective path arrived later,
-# so this test can only run on newer runtimes (or real accelerators)
-_multiprocess_cpu = pytest.mark.skipif(
-    tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5),
-    reason="CPU-backend multiprocess collectives need jax >= 0.5")
 
-
-@_multiprocess_cpu
 def test_two_process_job_agrees_on_global_state():
     lines = spawn_two_process_demo(devices_per_proc=4)
     assert len(lines) == 2
