@@ -54,6 +54,7 @@ from sitewhere_tpu.pipeline import (
     make_pipeline_step,
     make_presence_sweep,
 )
+from sitewhere_tpu.utils.tracing import stage
 
 
 # WAL record format tags (first byte of every logged payload): recovery
@@ -206,16 +207,16 @@ class IngestHostMixin:
         if self.wal is None or getattr(self._wal_local, "depth", 0):
             return
         head = tag + tenant.encode() + b"\x00"
-        rec = self.flight.current()
-        t0 = time.perf_counter()
-        self._wal_last_seq = self.wal.append_many(payloads, head)
-        if not self.wal.group_commit:
-            # ONE buffered write for the whole group, then one flush: an
-            # accepted event must survive a process crash (fsync cadence
-            # stays the operator's sync() call)
-            self.wal.flush()
-        rec.mark("wal_append")
-        rec.add("wal_flush_ms", round((time.perf_counter() - t0) * 1000, 3))
+        with stage("wal.append", mark="wal_append",
+                   records=len(payloads)) as sp:
+            b0 = self.wal.appended_bytes
+            self._wal_last_seq = self.wal.append_many(payloads, head)
+            if not self.wal.group_commit:
+                # ONE buffered write for the whole group, then one flush:
+                # an accepted event must survive a process crash (fsync
+                # cadence stays the operator's sync() call)
+                self.wal.flush()
+            sp.set_metadata(bytes=self.wal.appended_bytes - b0)
         feed = getattr(self, "replica_feed", None)
         if feed is not None:
             # same critical section as the append: feed order == WAL
@@ -239,12 +240,10 @@ class IngestHostMixin:
             # dispatch — stamping wal_durable here would claim a
             # durability guarantee that mode does not provide
             return
-        t0 = time.perf_counter()
-        self.wal.wait_durable(self._wal_last_seq)
-        dt = time.perf_counter() - t0
-        for rec in traces:
-            rec.mark("wal_durable")
-            rec.add("wal_gate_ms", round(dt * 1000, 3))
+        with stage("wal.gate", batches=len(traces)):
+            self.wal.wait_durable(self._wal_last_seq)
+            for rec in traces:
+                rec.mark("wal_durable")
 
     # ------------------------------------------------------- flight recorder
     def get_trace(self, trace_id: str) -> dict:
@@ -300,46 +299,49 @@ class IngestHostMixin:
         rec = self.flight.begin(
             "ingest", tenant=tenant, n_payloads=len(payloads),
             traceparent=traceparent or current_traceparent())
-        # weighted-fair turn (ISSUE 9): under multi-tenant contention the
-        # gate orders which tenant's batch enters the ingest critical
-        # section (and therefore acquires the next arena slot / staging
-        # room) by virtual-time deficit, so one tenant's flood cannot
-        # starve the others in lock-arrival order. The turn is ENTERED by
-        # the inner skeleton immediately before its branch's critical
-        # section, so work that deliberately runs outside the engine lock
-        # (the lenient path's native decode) keeps overlapping across
-        # threads with QoS on. Re-entrant callers (admin paths already
-        # inside the engine lock) skip the gate — parking them would
-        # deadlock against their own lock.
-        gate = self._wfq_gate
-        gate_ctx = (gate.turn(tenant, len(payloads))
-                    if gate is not None and not self.lock._is_owned()
-                    else contextlib.nullcontext())
-        with self.flight.bind(rec):
-            summary = self._ingest_batch_inner(payloads, tenant, tag,
-                                               dec, native_fn, binary,
-                                               rec, gate_ctx)
-        if rec.trace_id is not None:
-            rec.add_counts(summary)
-            if rec.meta.get("path") != "arena" and summary.get("staged"):
-                with self.lock:
-                    if self.staged_count:
-                        # rows await dispatch via the shared buffer: the
-                        # next flush stamps this record's dispatch
-                        self._staged_traces.append(rec)
-                    else:
-                        # a mid-ingest buffer-fill flush already
-                        # dispatched every row of this batch (the record
-                        # was not yet queued): join the newest in-flight
-                        # program so drain stamps the tail stages
-                        # instead of stranding an incomplete trace
-                        rec.mark("dispatch")
-                        if self._pending_traces:
-                            self._pending_traces[-1].append(rec)
+        # the batch's root span: every span below shares its trace id
+        with stage("ingest", payloads=len(payloads),
+                   trace_id=rec.trace_id or ""):
+            # weighted-fair turn: under multi-tenant contention the
+            # gate orders which tenant's batch enters the ingest critical
+            # section (and therefore acquires the next arena slot / staging
+            # room) by virtual-time deficit, so one tenant's flood cannot
+            # starve the others in lock-arrival order. The turn is ENTERED by
+            # the inner skeleton immediately before its branch's critical
+            # section, so work that deliberately runs outside the engine lock
+            # (the lenient path's native decode) keeps overlapping across
+            # threads with QoS on. Re-entrant callers (admin paths already
+            # inside the engine lock) skip the gate — parking them would
+            # deadlock against their own lock.
+            gate = self._wfq_gate
+            gate_ctx = (gate.turn(tenant, len(payloads))
+                        if gate is not None and not self.lock._is_owned()
+                        else contextlib.nullcontext())
+            with self.flight.bind(rec):
+                summary = self._ingest_batch_inner(payloads, tenant, tag,
+                                                   dec, native_fn, binary,
+                                                   rec, gate_ctx)
+            if rec.trace_id is not None:
+                rec.add_counts(summary)
+                if rec.meta.get("path") != "arena" and summary.get("staged"):
+                    with self.lock:
+                        if self.staged_count:
+                            # rows await dispatch via the shared buffer: the
+                            # next flush stamps this record's dispatch
+                            self._staged_traces.append(rec)
                         else:
-                            rec.mark("device_ready")
-            summary["trace_id"] = rec.trace_id
-        return summary
+                            # a mid-ingest buffer-fill flush already
+                            # dispatched every row of this batch (the record
+                            # was not yet queued): join the newest in-flight
+                            # program so drain stamps the tail stages
+                            # instead of stranding an incomplete trace
+                            rec.mark("dispatch")
+                            if self._pending_traces:
+                                self._pending_traces[-1].append(rec)
+                            else:
+                                rec.mark("device_ready")
+                summary["trace_id"] = rec.trace_id
+            return summary
 
     def _ingest_batch_inner(self, payloads, tenant, tag, dec, native_fn,
                             binary, rec,
@@ -352,10 +354,12 @@ class IngestHostMixin:
                 try:
                     predecoded = self._strict_predecode(payloads, dec)
                     self._wal_append(tag, payloads, tenant)
-                    summary = self._ingest_python_fallback(payloads, tenant,
-                                                           dec, predecoded)
-                    rec.mark("decode")
-                    rec.mark("commit")
+                    # the Python path decodes and stages in one pass
+                    with stage("ingest.commit", mark="commit") as sp:
+                        summary = self._ingest_python_fallback(
+                            payloads, tenant, dec, predecoded)
+                        rec.mark("decode")
+                        sp.set_metadata(staged=summary.get("staged", 0))
                     return summary
                 finally:
                     self._clear_now_pin()
@@ -366,14 +370,10 @@ class IngestHostMixin:
             with gate_ctx, self.lock:
                 try:
                     names_before = len(self.channel_map.names)
-                    res = native_fn(payloads)
-                    rec.mark("decode")
+                    res = self._native_decode(native_fn, payloads)
                     self._check_strict_native(res, names_before)
                     self._wal_append(tag, payloads, tenant)
-                    summary = self._ingest_decoded(res, payloads, tenant,
-                                                   dec)
-                    rec.mark("commit")
-                    return summary
+                    return self._stage_decoded(res, payloads, tenant, dec)
                 finally:
                     self._clear_now_pin()
         if getattr(self, "_arena_pool", None) is not None \
@@ -388,16 +388,30 @@ class IngestHostMixin:
         # lenient fast path: decode OUTSIDE the lock (concurrent receivers
         # decode in parallel — and outside the WFQ turn, for the same
         # reason); log + stage atomically
-        res = native_fn(payloads)
-        rec.mark("decode")
+        res = self._native_decode(native_fn, payloads)
         with gate_ctx, self.lock:
             try:
                 self._wal_append(tag, payloads, tenant)
-                summary = self._ingest_decoded(res, payloads, tenant, dec)
-                rec.mark("commit")
-                return summary
+                return self._stage_decoded(res, payloads, tenant, dec)
             finally:
                 self._clear_now_pin()
+
+    @staticmethod
+    def _native_decode(native_fn, payloads):
+        """The native SoA decode of a whole batch, as one span."""
+        with stage("ingest.decode", mark="decode",
+                   rows=len(payloads)) as sp:
+            res = native_fn(payloads)
+            sp.set_metadata(failed=int(np.sum(res.rtype < 0)))
+        return res
+
+    def _stage_decoded(self, res, payloads, tenant, dec) -> dict:
+        """Stage a natively decoded batch (caller holds the lock and has
+        logged it), as one span."""
+        with stage("ingest.commit", mark="commit") as sp:
+            summary = self._ingest_decoded(res, payloads, tenant, dec)
+            sp.set_metadata(staged=summary.get("staged", 0))
+        return summary
 
     def _strict_predecode(self, payloads, dec):
         """Strict pre-pass for the Python-fallback path: decode ONCE and
@@ -1934,16 +1948,20 @@ class Engine(IngestHostMixin):
                     # batch's trace; the engine lock serializes arena
                     # decode, so a plain attribute is race-free
                     dec.current_trace = rec.trace_id
-                n_ok, collisions = dec.decode_into(
-                    chunk, arena, lo, binary=binary)
-                rec.mark("decode")
+                with stage("ingest.decode", mark="decode",
+                           rows=take) as sp:
+                    n_ok, collisions = dec.decode_into(
+                        chunk, arena, lo, binary=binary)
+                    sp.set_metadata(failed=take - n_ok)
                 rec.mark("arena_fill")
                 if self._sharder is not None:
                     rec.add("ingest_workers", self._sharder.last_workers)
                 self._wal_append(tag, chunk, tenant)
-                self._arena_commit(arena, lo, take, chunk, tenant,
-                                   reg_decoder, now, base_ms, summary)
-                rec.mark("commit")
+                with stage("ingest.commit", mark="commit") as sp:
+                    staged0 = summary["staged"]
+                    self._arena_commit(arena, lo, take, chunk, tenant,
+                                       reg_decoder, now, base_ms, summary)
+                    sp.set_metadata(staged=summary["staged"] - staged0)
                 if rec.trace_id is not None:
                     arena.traces.append(rec)
                 self.channel_map.collisions += collisions
@@ -2085,7 +2103,8 @@ class Engine(IngestHostMixin):
         for rec in traces:
             rec.mark("dispatch")
         step = self._arena_step or self._step
-        self.state, out = step(self.state, arena.view_batch())
+        with stage("step.dispatch", rows=arena.cursor):
+            self.state, out = step(self.state, arena.view_batch())
         self._enqueue_out(out, traces)
         # the recycle wait that proves the transfer completed ALSO proves
         # the device program ran: device_ready harvests there, free
@@ -2188,9 +2207,11 @@ class Engine(IngestHostMixin):
             if (len(self._buf) or self._fair_queued or self._staged_batches
                     or (self._arena_fill is not None
                         and self._arena_fill.cursor)) and expired:
-                return self.flush()
+                with stage("flush"):
+                    return self.flush()
             if self._pending_outs and expired:
-                return _merge_summaries(self.drain())
+                with stage("flush"):
+                    return _merge_summaries(self.drain())
             return None
 
     def flush(self) -> dict:
@@ -2199,8 +2220,6 @@ class Engine(IngestHostMixin):
         cover several scan lanes, including empty padding lanes). On a
         pipeline error the flight recorder dumps the recent batch
         lifecycles before the error propagates."""
-        from sitewhere_tpu.utils.tracing import stage
-
         try:
             with self.lock, stage("pipeline_step"):
                 self.flush_async()
@@ -2254,7 +2273,8 @@ class Engine(IngestHostMixin):
                 for rec in traces:
                     rec.mark("dispatch")
                 self.ledger.add("dispatched_rows", n_staged)
-                self.state, out = self._step(self.state, batch)
+                with stage("step.dispatch", rows=n_staged):
+                    self.state, out = self._step(self.state, batch)
                 self._enqueue_out(out, traces)
                 # ring head has advanced: each staged row persists up to
                 # one event per active assignment — count the upper bound
@@ -2287,10 +2307,11 @@ class Engine(IngestHostMixin):
             self._wal_gate(traces)
             for rec in traces:
                 rec.mark("dispatch")
-            self.ledger.add("dispatched_rows",
-                            sum(int(np.sum(b.valid)) for b in chunk))
-            self.state, outs = self._scan_step(self.state,
-                                               pack_batches(chunk))
+            rows = sum(int(np.sum(b.valid)) for b in chunk)
+            self.ledger.add("dispatched_rows", rows)
+            with stage("step.dispatch", rows=rows):
+                self.state, outs = self._scan_step(self.state,
+                                                   pack_batches(chunk))
             self._enqueue_out(outs, traces)
             # spool accounting happens HERE, where the ring head actually
             # advances — NOT at staging time (a staged-but-undispatched
@@ -2308,12 +2329,13 @@ class Engine(IngestHostMixin):
         self._pending_traces.append(list(traces))
         d = max(1, self.config.dispatch_depth)
         if len(self._pending_outs) >= d:
-            jax.block_until_ready(self._pending_outs[-d].n_persisted)
-            # the wait observed that program's completion — stamp
-            # device_ready on its batches at zero extra sync cost
-            # (overwrite: a multi-chunk batch keeps its LAST chunk)
-            for rec in self._pending_traces[-d]:
-                rec.mark("device_ready")
+            with stage("step.wait", depth=d):
+                jax.block_until_ready(self._pending_outs[-d].n_persisted)
+                # the wait observed that program's completion — stamp
+                # device_ready on its batches at zero extra sync cost
+                # (overwrite: a multi-chunk batch keeps its LAST chunk)
+                for rec in self._pending_traces[-d]:
+                    rec.mark("device_ready")
 
     def barrier(self) -> None:
         """Dispatch ALL staged work and wait for completion WITHOUT any
@@ -2327,7 +2349,11 @@ class Engine(IngestHostMixin):
                 self.flush_async()
             self._dispatch_staged(all_batches=True)
             if self._pending_outs:
-                jax.block_until_ready(self._pending_outs[-1].n_persisted)
+                # depth 0: no program may stay outstanding (the waits of
+                # _enqueue_out carry the dispatch depth)
+                with stage("step.wait", depth=0):
+                    jax.block_until_ready(
+                        self._pending_outs[-1].n_persisted)
 
     def _archive_account(self, max_new_rows: int) -> None:
         """Track the upper bound of ring rows written by a dispatch; spool

@@ -248,72 +248,85 @@ def pipeline_step(
     reg = state.registry
     b = batch.capacity
 
+    # each stage runs under a named scope: the scope lands in every op's
+    # metadata, so a device trace can sum time per stage by name, whatever
+    # the fusions are numbered (benchmark/metrics/step_append_ms.py)
     # 1. device lookup (inbound-processing analog)
-    res = lookup_devices(reg, batch.token_id, batch.tenant_id, batch.valid)
+    with jax.named_scope("lookup"):
+        res = lookup_devices(reg, batch.token_id, batch.tenant_id,
+                             batch.valid)
 
     # 2. auto-registration of the miss set (device-registration analog)
-    if config.auto_register:
-        regres = register_misses(
-            reg,
-            state.next_device,
-            state.next_assignment,
-            batch.token_id,
-            batch.tenant_id,
-            res.miss,
-            jnp.int32(config.default_device_type),
-            jnp.int32(config.default_area),
-            jnp.int32(config.default_customer),
-        )
-        reg = regres.registry
-        next_device = regres.next_device
-        next_assignment = regres.next_assignment
-        n_registered = regres.n_registered
-        new_tokens = regres.new_tokens
-        reg_overflow = regres.overflow.astype(jnp.int32)
-        # re-lookup so this batch's events flow through for just-registered
-        # devices (the reference re-injects events after registration)
-        res = lookup_devices(reg, batch.token_id, batch.tenant_id, batch.valid)
-    else:
-        next_device = state.next_device
-        next_assignment = state.next_assignment
-        n_registered = jnp.zeros((), jnp.int32)
-        new_tokens = jnp.full((b,), NULL_ID, jnp.int32)
-        reg_overflow = jnp.zeros((), jnp.int32)
+    with jax.named_scope("register"):
+        if config.auto_register:
+            regres = register_misses(
+                reg,
+                state.next_device,
+                state.next_assignment,
+                batch.token_id,
+                batch.tenant_id,
+                res.miss,
+                jnp.int32(config.default_device_type),
+                jnp.int32(config.default_area),
+                jnp.int32(config.default_customer),
+            )
+            reg = regres.registry
+            next_device = regres.next_device
+            next_assignment = regres.next_assignment
+            n_registered = regres.n_registered
+            new_tokens = regres.new_tokens
+            reg_overflow = regres.overflow.astype(jnp.int32)
+            # re-lookup so this batch's events flow through for
+            # just-registered devices (the reference re-injects events
+            # after registration)
+            res = lookup_devices(reg, batch.token_id, batch.tenant_id,
+                                 batch.valid)
+        else:
+            next_device = state.next_device
+            next_assignment = state.next_assignment
+            n_registered = jnp.zeros((), jnp.int32)
+            new_tokens = jnp.full((b,), NULL_ID, jnp.int32)
+            reg_overflow = jnp.zeros((), jnp.int32)
 
-    # remaining misses -> dead-letter list (unregistered-device-events analog)
-    n_miss, perm = compact_valid_front(res.miss)
-    dead_tokens = jnp.where(jnp.arange(b) < n_miss, batch.token_id[perm], NULL_ID)
+        # remaining misses -> dead-letter list (unregistered-device-events
+        # analog)
+        n_miss, perm = compact_valid_front(res.miss)
+        dead_tokens = jnp.where(jnp.arange(b) < n_miss,
+                                batch.token_id[perm], NULL_ID)
 
     # 3. per-assignment expansion (PreprocessedEventMapper flatMap analog)
-    exp = expand_assignments(reg, res)
+    with jax.named_scope("expand"):
+        exp = expand_assignments(reg, res)
 
     # 4. persistence append (event-management analog)
-    src = exp.source_row
-    persist = append_events(
-        state.store,
-        valid=exp.valid,
-        etype=batch.etype[src],
-        device=exp.device,
-        assignment=exp.assignment,
-        tenant=batch.tenant_id[src],
-        area=exp.area,
-        customer=exp.customer,
-        asset=exp.asset,
-        ts_ms=batch.ts_ms[src],
-        received_ms=batch.received_ms[src],
-        values=batch.values[src],
-        vmask=batch.vmask[src],
-        aux=batch.aux[src],
-    )
+    with jax.named_scope("append"):
+        src = exp.source_row
+        persist = append_events(
+            state.store,
+            valid=exp.valid,
+            etype=batch.etype[src],
+            device=exp.device,
+            assignment=exp.assignment,
+            tenant=batch.tenant_id[src],
+            area=exp.area,
+            customer=exp.customer,
+            asset=exp.asset,
+            ts_ms=batch.ts_ms[src],
+            received_ms=batch.received_ms[src],
+            values=batch.values[src],
+            vmask=batch.vmask[src],
+            aux=batch.aux[src],
+        )
 
     # 5. telemetry-window update for the analytics service (devices with
     #    dense id < analytics capacity get HBM-resident sliding windows)
     windows = state.windows
     if windows is not None:
-        windows = append_measurements(
-            windows, res.device, res.found, batch.etype, batch.ts_ms,
-            batch.seq, batch.values,
-        )
+        with jax.named_scope("windows"):
+            windows = append_measurements(
+                windows, res.device, res.found, batch.etype, batch.ts_ms,
+                batch.seq, batch.values,
+            )
 
     # 5.5 streaming-rules CEP tier (ops/rules.py): standing rules +
     #     continuous rollups evaluate on the post-lookup view INSIDE this
@@ -322,20 +335,22 @@ def pipeline_step(
     #     reporting cadence (Engine.poll_rule_fires); nothing here syncs.
     rules = state.rules
     if rules is not None:
-        rules = rules_update(rules, batch, res.device, res.found, reg)
+        with jax.named_scope("rules"):
+            rules = rules_update(rules, batch, res.device, res.found, reg)
 
     # 6. windowed device-state merge (device-state analog)
-    new_device_state = merge_batch_state(
-        state.device_state,
-        dev=res.device,
-        found=res.found,
-        etype=batch.etype,
-        ts_ms=batch.ts_ms,
-        seq=batch.seq,
-        values=batch.values,
-        vmask=batch.vmask,
-        aux=batch.aux,
-    )
+    with jax.named_scope("merge"):
+        new_device_state = merge_batch_state(
+            state.device_state,
+            dev=res.device,
+            found=res.found,
+            etype=batch.etype,
+            ts_ms=batch.ts_ms,
+            seq=batch.seq,
+            values=batch.values,
+            vmask=batch.vmask,
+            aux=batch.aux,
+        )
 
     n_found = jnp.sum(res.found.astype(jnp.int32))
     m = state.metrics

@@ -29,8 +29,9 @@ from __future__ import annotations
 import threading
 import time
 
-from sitewhere_tpu.utils.tracing import (current_traceparent, new_trace_id,
-                                         trace_id_of)
+from sitewhere_tpu.utils.tracing import (_WALL_ANCHOR_NS, bind_record,
+                                         bound_record, current_traceparent,
+                                         new_trace_id, trace_id_of)
 
 # canonical stage ordering for rendering (records carry only the stages
 # their path actually visited). ``wal_durable`` is the group-commit
@@ -105,10 +106,13 @@ def stage_durations(stages_us: dict) -> dict:
 class FlightRecord:
     """One batch's lifecycle. Stage marks are idempotent-overwrite (a
     multi-chunk ingest keeps the LAST completion per stage); ``meta``
-    carries counts and path annotations."""
+    carries counts and path annotations. The start is a perf_counter_ns
+    stamp placed on wall time by the process anchor the spans use
+    (``tracing._WALL_ANCHOR_NS``), so ``startedNs`` + ``stagesUs`` lines
+    up with a profiler trace's wall clock."""
 
     __slots__ = ("trace_id", "kind", "tenant", "rank", "n_payloads",
-                 "t0_unix_ms", "t0_ns", "stages", "meta", "harvested")
+                 "t0_ns", "stages", "meta", "harvested")
 
     def __init__(self, trace_id: str | None, kind: str, tenant: str,
                  rank: int, n_payloads: int):
@@ -117,7 +121,6 @@ class FlightRecord:
         self.tenant = tenant
         self.rank = rank
         self.n_payloads = n_payloads
-        self.t0_unix_ms = int(time.time() * 1000)
         self.t0_ns = time.perf_counter_ns()
         self.stages: dict[str, int] = {}
         self.meta: dict[str, object] = {}
@@ -144,9 +147,12 @@ class FlightRecord:
         may read a record the ingest thread is still marking."""
         stages = dict(self.stages)
         meta = dict(self.meta)
+        started_ns = self.t0_ns + _WALL_ANCHOR_NS
         return {"traceId": self.trace_id, "kind": self.kind,
                 "tenant": self.tenant, "rank": self.rank,
-                "payloads": self.n_payloads, "startedMs": self.t0_unix_ms,
+                "payloads": self.n_payloads,
+                "startedMs": started_ns // 1_000_000,
+                "startedNs": started_ns,
                 "stagesUs": {name: round((ns - self.t0_ns) / 1000.0, 1)
                              for name, ns in stages.items()},
                 **meta}
@@ -182,7 +188,8 @@ class FlightRecorder:
     ``begin`` allocates a slot (evicting the oldest) under a short lock;
     everything after that is lock-free record mutation. ``bind`` exposes
     the batch's record to nested layers (the WAL append lives three
-    frames below the ingest entry point) via a thread-local.
+    frames below the ingest entry point) via a thread-local, the one
+    ``tracing.stage`` marks.
     """
 
     def __init__(self, capacity: int = 1024, rank: int = 0,
@@ -196,7 +203,6 @@ class FlightRecorder:
         self._head = 0
         self._by_id: dict[str, list[FlightRecord]] = {}
         self._lock = threading.Lock()
-        self._local = threading.local()
         self.dropped = 0    # records evicted before ever being read
 
     # ------------------------------------------------------------ record
@@ -227,22 +233,12 @@ class FlightRecorder:
         return rec
 
     def bind(self, rec):
-        """Context manager making ``rec`` this thread's current record."""
-        recorder = self
-
-        class _Bind:
-            def __enter__(self):
-                self.prev = getattr(recorder._local, "rec", None)
-                recorder._local.rec = rec
-                return rec
-
-            def __exit__(self, *exc):
-                recorder._local.rec = self.prev
-
-        return _Bind()
+        """Context manager making ``rec`` this thread's current record
+        (one binding per thread, shared with ``tracing.stage``)."""
+        return bind_record(rec)
 
     def current(self) -> FlightRecord | _NullRecord:
-        rec = getattr(self._local, "rec", None)
+        rec = bound_record()
         return rec if rec is not None else NULL_RECORD
 
     # ------------------------------------------------------------- query

@@ -93,6 +93,7 @@ class IngestLog:
         self._closed = False
         self._commit_err: BaseException | None = None
         self.fsyncs = 0             # commit fsyncs (amortization proof)
+        self.appended_bytes = 0     # framed record bytes append_many took
         self.commit_groups = 0      # append groups covered by them
         self._commit_hook = None    # test injection point (pre-fsync)
         if self.group_commit:
@@ -140,6 +141,7 @@ class IngestLog:
             frames += head
             frames += p
         with self._lock:
+            self.appended_bytes += len(frames)
             if self.group_commit:
                 return self._buffer_frames(frames)
             self._fh.write(frames)

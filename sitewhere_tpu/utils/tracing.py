@@ -56,6 +56,7 @@ _STAGE_HIST = REGISTRY.histogram(
     "swtpu_stage_seconds", "host pipeline stage latency"
 )
 
+# this thread's bound flight record (utils/flight.FlightRecorder.bind)
 _local = threading.local()
 
 # ------------------------------------------------------------ traceparent
@@ -111,19 +112,45 @@ def bind_traceparent(traceparent: str | None):
 
 
 @contextlib.contextmanager
-def stage(name: str, **labels):
-    """Span for one pipeline stage; nests (child spans record their own
-    stage label), observations land in the shared histogram."""
+def stage(name: str, mark: str | None = None, **counts):
+    """Span for one pipeline stage, written into the profiler's own trace
+    as ``swtpu.<name>`` with ``counts`` as its args, so it shares the
+    device ops' clock. With no profiler running the annotation is a no-op
+    check. Yields the annotation: counts known only at the end go in with
+    ``set_metadata``. At exit, ``mark`` (a flight stage) is stamped on the
+    thread's bound flight record, if there is one; the duration lands in
+    the ``swtpu_stage_seconds`` histogram."""
+    import jax
+
     t0 = time.perf_counter()
-    stack = getattr(_local, "stack", None)
-    if stack is None:
-        stack = _local.stack = []
-    stack.append(name)
+    span = jax.profiler.TraceAnnotation("swtpu." + name, **counts)
     try:
-        yield
+        with span:
+            yield span
+        if mark is not None:
+            rec = getattr(_local, "rec", None)
+            if rec is not None:
+                rec.mark(mark)
     finally:
-        stack.pop()
-        _STAGE_HIST.observe(time.perf_counter() - t0, stage=name, **labels)
+        _STAGE_HIST.observe(time.perf_counter() - t0, stage=name)
+
+
+@contextlib.contextmanager
+def bind_record(rec):
+    """Make ``rec`` this thread's flight record for the enclosed block:
+    the record that :func:`stage` marks and ``FlightRecorder.current``
+    returns."""
+    prev = getattr(_local, "rec", None)
+    _local.rec = rec
+    try:
+        yield rec
+    finally:
+        _local.rec = prev
+
+
+def bound_record():
+    """The flight record bound to this thread, or None."""
+    return getattr(_local, "rec", None)
 
 
 @contextlib.contextmanager
@@ -136,23 +163,6 @@ def device_trace(log_dir: str):
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Decorator: trace a function as a stage span + XLA annotation."""
-    import functools
-
-    import jax
-
-    def wrap(fn):
-        @functools.wraps(fn)
-        def inner(*args, **kwargs):
-            with stage(name), jax.profiler.TraceAnnotation(name):
-                return fn(*args, **kwargs)
-
-        return inner
-
-    return wrap
 
 
 # ==========================================================================
