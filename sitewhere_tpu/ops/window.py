@@ -10,8 +10,10 @@ keeps the latest value plus the 3 most recent events per event class
 One call merges one batch/window of events into the HBM-resident
 ``DeviceStateStore``:
   * recent-event rings (depth R=3, most-recent-first) per class are updated
-    with a sort + rank-from-end + masked scatter, then a fixed-size row-wise
-    top-R merge against the existing ring — no data-dependent shapes.
+    with a batch sort + rank-from-end + masked scatter into a batch ring,
+    then a row-wise top-R merge against the existing ring that ranks the 2R
+    candidates by pairwise compares and selects — elementwise over devices,
+    no per-device sort or gather, no data-dependent shapes.
   * latest-per-channel measurement values use an argmax-scatter over
     (device, channel) segments — exact even with duplicate timestamps
     (batch sequence breaks ties), robust under at-least-once replay.
@@ -26,6 +28,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from sitewhere_tpu.core.state import LOC_LANES, RECENT_DEPTH, DeviceStateStore
 from sitewhere_tpu.core.types import NUM_EVENT_TYPES, EventType, PresenceState
@@ -73,27 +76,54 @@ def _merge_rings(
     """Row-wise top-R merge of batch ring + existing ring (most-recent-first).
 
     New entries are preferred on timestamp ties (later arrival wins, matching
-    the reference merge strategy's replace-on-merge behavior)."""
-    r_depth = RECENT_DEPTH
-    cat_valid = jnp.concatenate([new_valid, old_valid], axis=1)   # [N, 2R]
-    cat_ts = jnp.concatenate([new_ts, old_ts], axis=1)
-    # row-wise stable lexicographic sort: invalid last, then ts descending.
-    # Two separate keys — packing into one int32 would collide real
-    # near-INT32_MIN timestamps with the invalid sentinel.
-    idx = jnp.broadcast_to(jnp.arange(cat_ts.shape[1], dtype=jnp.int32), cat_ts.shape)
-    _, _, order = jax.lax.sort(
-        [(~cat_valid).astype(jnp.int32), -jnp.maximum(cat_ts, _NEG_SAFE_MIN), idx],
-        dimension=1, num_keys=2, is_stable=True,
-    )
-    order = order[:, :r_depth]
-    out_valid = jnp.take_along_axis(cat_valid, order, axis=1)
-    out_ts = jnp.take_along_axis(cat_ts, order, axis=1)
-    out_lanes = []
-    for new_lane, old_lane in zip(new_lanes, old_lanes):
-        cat = jnp.concatenate([new_lane, old_lane], axis=1)
-        idx = order.reshape(order.shape + (1,) * (cat.ndim - 2))
-        out_lanes.append(jnp.take_along_axis(cat, jnp.broadcast_to(idx, order.shape + cat.shape[2:]), axis=1))
-    return out_valid, out_ts, out_lanes
+    the reference merge strategy's replace-on-merge behavior).
+
+    Candidate i of a row is new slot i, then old slot i - R_new. Its sort key
+    is (invalid, -max(ts, INT32_MIN + 1), i): invalid last, newest first,
+    ties by position. Rank each candidate by counting the candidates whose
+    key is smaller (pairwise compares of [N] vectors; the ranks are a
+    permutation), then fill output slot r with the candidate of rank r by a
+    select chain. Everything is elementwise over rows, so it fuses into one
+    pass over the rings with no row sort and no gather, and values are copied
+    bit for bit (NaN, -0.0 and int lanes unchanged)."""
+    def candidates(new: jax.Array, old: jax.Array) -> list[jax.Array]:
+        # device axis last and minor-most ([..., N]), as the TPU lays out the
+        # state: a candidate is a contiguous slice and a rank broadcasts over
+        # its lanes. Left free, the compiler lays the selects out
+        # channel-minor, each [8]-lane row padded to 128 lanes.
+        def device_minor(x: jax.Array) -> jax.Array:
+            x = jnp.moveaxis(x, 0, -1)
+            return with_layout_constraint(x, Layout(tuple(range(x.ndim))))
+
+        new_t, old_t = device_minor(new), device_minor(old)
+        return [new_t[i] for i in range(new_t.shape[0])] + [old_t[i] for i in range(old_t.shape[0])]
+
+    valid = candidates(new_valid, old_valid)  # bool[N] each
+    ts = candidates(new_ts, old_ts)           # int32[N] each
+    # two keys, not one packed int32: packing would collide real
+    # near-INT32_MIN timestamps with the invalid sentinel
+    key_ts = [jnp.maximum(t, _NEG_SAFE_MIN) for t in ts]
+    k = len(ts)
+    rank = [jnp.zeros_like(ts[0]) for _ in range(k)]
+    for i in range(k):
+        for j in range(i):
+            # candidate j (earlier) sorts before i unless i wins on the keys
+            j_first = (valid[j] & ~valid[i]) | ((valid[j] == valid[i]) & (key_ts[j] >= key_ts[i]))
+            rank[i] = rank[i] + j_first.astype(jnp.int32)
+            rank[j] = rank[j] + (~j_first).astype(jnp.int32)
+
+    def select(cands: list[jax.Array]) -> jax.Array:
+        """[N, R, ...]: slot r takes the candidate whose rank is r."""
+        slots = []
+        for r in range(RECENT_DEPTH):
+            out = cands[-1]
+            for i in range(k - 2, -1, -1):
+                out = jnp.where(rank[i] == r, cands[i], out)
+            slots.append(out)
+        return jnp.moveaxis(jnp.stack(slots), -1, 0)
+
+    out_lanes = [select(candidates(n, o)) for n, o in zip(new_lanes, old_lanes)]
+    return select(valid), select(ts), out_lanes
 
 
 def merge_batch_state(
