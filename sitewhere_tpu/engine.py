@@ -829,6 +829,10 @@ class EngineConfig:
                                        # one dict add per batch + one
                                        # np.sum per dispatch — bench
                                        # hard-gates the delta <= 3%
+    shards: int = 1                    # chips the device plane spans:
+                                       # Engine(config) with shards > 1
+                                       # is the SPMD engine over that
+                                       # many devices (parallel/sharded)
 
 
 @dataclasses.dataclass
@@ -1420,7 +1424,17 @@ class _PrecompiledStep:
 
 
 class Engine(IngestHostMixin):
-    """Single-node engine instance."""
+    """Single-node engine instance. ``Engine(config)`` with
+    ``config.shards > 1`` constructs the SPMD engine
+    (:class:`~sitewhere_tpu.parallel.sharded.SpmdEngine`) over that many
+    devices: one entry point for one chip or several."""
+
+    def __new__(cls, config: EngineConfig | None = None, *args, **kwargs):
+        if cls is Engine and config is not None and config.shards > 1:
+            from sitewhere_tpu.parallel.sharded import SpmdEngine
+
+            cls = SpmdEngine
+        return super().__new__(cls)
 
     def __init__(self, config: EngineConfig | None = None):
         self.config = config or EngineConfig()

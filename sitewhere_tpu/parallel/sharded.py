@@ -413,6 +413,7 @@ from sitewhere_tpu.parallel.placement import (  # noqa: E402
     slot_for_token,
 )
 from sitewhere_tpu.utils.shardobs import ShardHeatTracker  # noqa: E402
+from sitewhere_tpu.utils.tracing import stage  # noqa: E402
 
 # budgeted per-engine scope names for the fused SPMD programs (distinct
 # from the unbudgeted module-global shims above: an SpmdEngine dispatches
@@ -427,7 +428,8 @@ def _make_spmd_step(mesh, config: PipelineConfig):
     """The fused cross-shard ingest step: ONE jit program that shard_maps
     the single-chip pipeline step over the stacked ``[S, ...]`` state and
     a stacked ``[S, B, ...]`` batch. Identical math per shard — the fused
-    program IS ``pipeline_step``, once per chip, in one dispatch."""
+    program IS ``pipeline_step``, once per chip, in one dispatch. It runs
+    as ``jit_spmd_pipeline_step``, the name a device trace shows."""
     def local_step(state_blk, batch_blk):
         lstate = jax.tree_util.tree_map(lambda x: x[0], state_blk)
         lbatch = jax.tree_util.tree_map(lambda x: x[0], batch_blk)
@@ -444,7 +446,11 @@ def _make_spmd_step(mesh, config: PipelineConfig):
         out_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
         check_vma=False,
     )
-    return jax.jit(fused, donate_argnums=(0,))
+
+    def spmd_pipeline_step(state, batch):
+        return fused(state, batch)
+
+    return jax.jit(spmd_pipeline_step, donate_argnums=(0,))
 
 
 def _make_spmd_scan_step(mesh, config: PipelineConfig, capacity: int,
@@ -455,7 +461,8 @@ def _make_spmd_scan_step(mesh, config: PipelineConfig, capacity: int,
     per shard in ONE dispatch (one transfer group + one program launch,
     fused across the mesh). Only the state
     donates; the stacked batch rides in whole, exactly the single-chip
-    ``make_arena_scan_step`` donation discipline."""
+    ``make_arena_scan_step`` donation discipline. It runs as
+    ``jit_spmd_scan_step``."""
     def local_step(state_blk, batch_blk):
         lstate = jax.tree_util.tree_map(lambda x: x[0], state_blk)
         lbatch = jax.tree_util.tree_map(lambda x: x[0], batch_blk)
@@ -478,7 +485,11 @@ def _make_spmd_scan_step(mesh, config: PipelineConfig, capacity: int,
         out_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
         check_vma=False,
     )
-    return jax.jit(fused, donate_argnums=(0,))
+
+    def spmd_scan_step(state, batch):
+        return fused(state, batch)
+
+    return jax.jit(spmd_scan_step, donate_argnums=(0,))
 
 
 def _spmd_sweep(state: PipelineState, now_ms, missing_ms):
@@ -506,6 +517,15 @@ def _spmd_tenant_counts(state: PipelineState, t_cap: int):
                         state.registry.device_tenant,
                         state.device_state.event_counts)
     return per.sum(axis=0)
+
+
+@jax.jit
+def _device_rows(device_state, device):
+    """Row ``device`` of every device-state field on every shard, in one
+    program: each chip slices its own block, with no cross-chip traffic
+    (one dispatch and one small transfer, not one per field); the caller
+    keeps its shard's row."""
+    return jax.tree_util.tree_map(lambda x: x[:, device], device_state)
 
 
 def _broadcast_tree(tree, n: int):
@@ -645,7 +665,11 @@ class SpmdEngine(Engine):
 
     def __init__(self, config: EngineConfig | None = None,
                  n_shards: int | None = None, arena: bool = True):
+        """``n_shards``, when given, overrides ``config.shards``; either
+        way ``self.config.shards`` is the number of devices spanned."""
         cfg0 = config or EngineConfig()
+        if n_shards is None:
+            n_shards = cfg0.shards
         for bad, why in (
                 (cfg0.archive_dir, "archive tier"),
                 (cfg0.analytics_devices, "analytics window"),
@@ -656,6 +680,9 @@ class SpmdEngine(Engine):
                 raise ValueError(f"SpmdEngine does not support {why} (v1)")
         mesh = make_mesh(n_shards)
         n = mesh.devices.size
+        if n != n_shards:
+            raise ValueError(f"SpmdEngine over {n_shards} shards needs as "
+                             f"many devices; JAX sees {n}")
         # arena=False keeps the per-row host router on the batch path —
         # the byte-identity oracle and bench contrast baseline
         self._spmd_arena = bool(arena) and cfg0.ingest_arenas >= 0
@@ -665,7 +692,7 @@ class SpmdEngine(Engine):
         # the mesh exists — _build_arena_machinery defers until then)
         super().__init__(dataclasses.replace(
             cfg0, use_native=cfg0.use_native and self._spmd_arena,
-            token_capacity=cfg0.token_capacity * n))
+            token_capacity=cfg0.token_capacity * n, shards=n))
         c = self.config
         self.mesh = mesh
         self.n_shards = n
@@ -772,19 +799,17 @@ class SpmdEngine(Engine):
                 self._route_slot[token_id] = slot
         return route
 
-    def _route_rows(self, tids: np.ndarray):
-        """Vectorized (shard, local_tid) for a whole batch of global token
-        ids. Unseen tokens route through :meth:`_route_token` in FIRST-
-        OCCURRENCE order so local ids allocate exactly as the per-row
-        router would — the store byte-identity invariant."""
-        sh = self._route_shard[tids]
-        if (sh < 0).any():
-            miss = tids[sh < 0]
+    def _route_new(self, tids: np.ndarray) -> None:
+        """Route the batch's unseen global token ids through
+        :meth:`_route_token` in FIRST-OCCURRENCE order, so local ids
+        allocate exactly as the per-row router would — the store
+        byte-identity invariant. Afterwards ``_route_shard`` and
+        ``_route_ltid`` give every row's route by two indexed loads."""
+        miss = tids[self._route_shard[tids] < 0]
+        if miss.size:
             _, first = np.unique(miss, return_index=True)
             for t in miss[np.sort(first)]:
                 self._route_token(int(t))
-            sh = self._route_shard[tids]
-        return sh, self._route_ltid[tids]
 
     # -------------------------------------------------------------- ingest
     def _stage_row(self, et, token_id, tenant_id, ts, now, values, mask,
@@ -828,18 +853,24 @@ class SpmdEngine(Engine):
         if native_fn is None:
             with gate_ctx, self.lock:
                 try:
-                    res = self._decode_batch_py(payloads, dec)
+                    with stage("ingest.decode", mark="decode",
+                               rows=len(payloads)) as sp:
+                        res = self._decode_batch_py(payloads, dec)
+                        if res is not None:
+                            sp.set_metadata(
+                                failed=int(np.sum(res.rtype < 0)))
                     if res is None:
                         # mixed/stream envelopes: whole batch takes the
                         # per-request path (exact single-chip semantics)
                         predecoded = self._strict_predecode(payloads, dec)
                         self._wal_append(tag, payloads, tenant)
-                        summary = self._ingest_python_fallback(
-                            payloads, tenant, dec, predecoded)
-                        rec.mark("decode")
-                        rec.mark("commit")
+                        with stage("ingest.commit", mark="commit") as sp:
+                            summary = self._ingest_python_fallback(
+                                payloads, tenant, dec, predecoded)
+                            rec.mark("decode")
+                            sp.set_metadata(
+                                staged=summary.get("staged", 0))
                         return summary
-                    rec.mark("decode")
                     self._wal_append(tag, payloads, tenant)
                     return self._ingest_decoded_spmd(res, payloads, tenant,
                                                      dec, rec)
@@ -849,8 +880,7 @@ class SpmdEngine(Engine):
             with gate_ctx, self.lock:
                 try:
                     names_before = len(self.channel_map.names)
-                    res = native_fn(payloads)
-                    rec.mark("decode")
+                    res = self._native_decode(native_fn, payloads)
                     self._check_strict_native(res, names_before)
                     self._wal_append(tag, payloads, tenant)
                     return self._ingest_decoded_spmd(res, payloads, tenant,
@@ -859,8 +889,7 @@ class SpmdEngine(Engine):
                     self._clear_now_pin()
         # lenient fast path: native decode OUTSIDE the lock (and the WFQ
         # turn) so concurrent receivers decode in parallel
-        res = native_fn(payloads)
-        rec.mark("decode")
+        res = self._native_decode(native_fn, payloads)
         with gate_ctx, self.lock:
             try:
                 self._wal_append(tag, payloads, tenant)
@@ -991,11 +1020,17 @@ class SpmdEngine(Engine):
         ack envelopes re-route through the per-request slow path exactly
         like single-chip (:meth:`Engine._decode_prologue`); their tokens
         pre-route in payload order so local token ids allocate exactly as
-        the per-row router would — the store byte-identity invariant."""
+        the per-row router would — the store byte-identity invariant.
+
+        Spans: ``swtpu.ingest.commit`` (``staged``) over the whole call,
+        and one ``swtpu.ingest.route`` per pass of routing and scatter
+        (``rows`` staged, ``lane_max``/``lane_min``: the most and fewest
+        of them that went to one shard); a lane that overflows dispatches
+        between two passes, outside either."""
         from sitewhere_tpu.ingest.fast_decode import RT_MAP
 
         rec.add("path", "arena")
-        with self.lock:
+        with self.lock, stage("ingest.commit", mark="commit") as span:
             now = self.epoch.now_ms()
             base_ms = int(self.epoch.base_unix_s * 1000)
             tids = res.token_id
@@ -1003,11 +1038,8 @@ class SpmdEngine(Engine):
             # order: event + ack rows (staged) and register rows (routed
             # by register_device). MAP rows never allocate a route.
             routable = (tids >= 0) & (res.rtype != RT_MAP)
-            sh = np.full(len(tids), -1, np.int32)
-            ltid = np.full(len(tids), -1, np.int32)
             if routable.any():
-                sh[routable], ltid[routable] = \
-                    self._route_rows(tids[routable])
+                self._route_new(tids[routable])
             rec.mark("route")
             etype, ok, ts_rel, values, failed, n_reg_ok = \
                 self._decode_prologue(res, payloads, tenant, reg_decoder,
@@ -1021,28 +1053,33 @@ class SpmdEngine(Engine):
                 if arena is None:
                     arena = self._arena_fill = \
                         self._acquire_arena(tenant, int(rem.size))
-                rs = sh[rem]
-                # per-shard running offsets within this chunk (<= n_shards
-                # bincount-style groups, never per-row Python)
-                cum = np.empty(rem.size, np.int64)
-                for s in np.unique(rs):
-                    m = rs == s
-                    cum[m] = np.arange(int(m.sum()))
-                dst = arena.cursors[rs] + cum
-                fit = dst < arena.rows
-                rows_f, rs_f, dst_f = rem[fit], rs[fit], dst[fit]
-                arena.etype[rs_f, dst_f] = etype[rows_f]
-                arena.token_id[rs_f, dst_f] = ltid[rows_f]
-                arena.tenant_id[rs_f, dst_f] = tenant_id
-                arena.ts_ms[rs_f, dst_f] = ts_rel[rows_f]
-                arena.received_ms[rs_f, dst_f] = now
-                arena.values[rs_f, dst_f] = values[rows_f]
-                arena.vmask[rs_f, dst_f] = res.chmask[rows_f]
-                arena.aux[rs_f, dst_f, 0] = res.aux0[rows_f]
-                arena.aux[rs_f, dst_f, 1] = res.aux1[rows_f]
-                arena.valid[rs_f, dst_f] = True
-                binc = np.bincount(rs_f, minlength=self.n_shards)
-                arena.cursors += binc
+                with stage("ingest.route") as route:
+                    rt = tids[rem]
+                    rs = self._route_shard[rt]
+                    # per-shard running offsets within this pass
+                    # (<= n_shards groups, never per-row Python)
+                    cum = np.empty(rem.size, np.int64)
+                    for s in np.unique(rs):
+                        m = rs == s
+                        cum[m] = np.arange(int(m.sum()))
+                    dst = arena.cursors[rs] + cum
+                    fit = dst < arena.rows
+                    rows_f, rs_f, dst_f = rem[fit], rs[fit], dst[fit]
+                    arena.etype[rs_f, dst_f] = etype[rows_f]
+                    arena.token_id[rs_f, dst_f] = self._route_ltid[rt[fit]]
+                    arena.tenant_id[rs_f, dst_f] = tenant_id
+                    arena.ts_ms[rs_f, dst_f] = ts_rel[rows_f]
+                    arena.received_ms[rs_f, dst_f] = now
+                    arena.values[rs_f, dst_f] = values[rows_f]
+                    arena.vmask[rs_f, dst_f] = res.chmask[rows_f]
+                    arena.aux[rs_f, dst_f, 0] = res.aux0[rows_f]
+                    arena.aux[rs_f, dst_f, 1] = res.aux1[rows_f]
+                    arena.valid[rs_f, dst_f] = True
+                    binc = np.bincount(rs_f, minlength=self.n_shards)
+                    arena.cursors += binc
+                    route.set_metadata(rows=int(rows_f.size),
+                                       lane_max=int(binc.max()),
+                                       lane_min=int(binc.min()))
                 if self.ledger.enabled:
                     self._shard_rows_routed += binc
                 if self.shard_heat.enabled and rows_f.size:
@@ -1061,7 +1098,6 @@ class SpmdEngine(Engine):
                     rem = rem[~fit]
                 else:
                     rem = rem[:0]
-            rec.mark("commit")
             arena = self._arena_fill
             if arena is not None and \
                     int(arena.cursors.min()) >= arena.rows:
@@ -1070,6 +1106,7 @@ class SpmdEngine(Engine):
             self.host_counters["arena_rows"] = \
                 self.host_counters.get("arena_rows", 0) + staged
             self.ledger.add("staged_rows", staged)
+            span.set_metadata(staged=staged)
         return {"decoded": staged + n_reg_ok, "failed": failed,
                 "staged": staged}
 
@@ -1103,10 +1140,12 @@ class SpmdEngine(Engine):
                 rec.add("shard_rows",
                         "/".join(str(int(x)) for x in per_shard))
                 rec.add("skew", round(skew, 3))
-        batch = arena.view_batch()
-        batch = jax.device_put(batch, stack_sharding(self.mesh, batch))
         step = self._arena_step or self._step
-        self.state, out = step(self.state, batch)
+        with stage("step.dispatch", rows=int(per_shard.sum()),
+                   shards=self.n_shards, lane_max=int(per_shard.max())):
+            batch = arena.view_batch()
+            batch = jax.device_put(batch, stack_sharding(self.mesh, batch))
+            self.state, out = step(self.state, batch)
         self._enqueue_out(out, traces)
         # the recycle wait that proves the transfer completed ALSO proves
         # the device program ran: device_ready harvests there, free
@@ -1144,13 +1183,16 @@ class SpmdEngine(Engine):
             batches = [b.emit() for b in self._shard_bufs]
             batch = jax.tree_util.tree_map(lambda *xs: np.stack(xs),
                                            *batches)
-            batch = jax.device_put(batch, stack_sharding(self.mesh, batch))
             traces, self._staged_traces = self._staged_traces, []
             self._wal_gate(traces)
             for rec in traces:
                 rec.mark("dispatch")
             self.ledger.add("dispatched_rows", n_staged)
-            self.state, out = self._step(self.state, batch)
+            with stage("step.dispatch", rows=n_staged, shards=self.n_shards,
+                       lane_max=int(lens.max())):
+                batch = jax.device_put(batch,
+                                       stack_sharding(self.mesh, batch))
+                self.state, out = self._step(self.state, batch)
             self._enqueue_out(out, traces)
             self._last_flush = time.monotonic()
 
@@ -1178,9 +1220,11 @@ class SpmdEngine(Engine):
                        >= self.config.flush_interval_s)
             if (any(len(b) for b in self._shard_bufs)
                     or self._arena_backlogged()) and expired:
-                return self.flush()
+                with stage("flush"):
+                    return self.flush()
             if self._pending_outs and expired:
-                return _merge_summaries(self.drain())
+                with stage("flush"):
+                    return _merge_summaries(self.drain())
             return None
 
     def barrier(self) -> None:
@@ -1189,7 +1233,9 @@ class SpmdEngine(Engine):
                    or self._arena_backlogged()):
                 self.flush_async()
             if self._pending_outs:
-                jax.block_until_ready(self._pending_outs[-1].n_persisted)
+                with stage("step.wait", depth=0):
+                    jax.block_until_ready(
+                        self._pending_outs[-1].n_persisted)
 
     def drain(self) -> list[dict]:
         with self.lock:
@@ -1559,8 +1605,8 @@ class SpmdEngine(Engine):
                 return None
             s, d = divmod(did, self._device_cap)
             ds = jax.tree_util.tree_map(
-                lambda x, _s=s, _d=d: np.asarray(jax.device_get(x[_s, _d])),
-                self.state.device_state)
+                lambda x, _s=s: x[_s],
+                jax.device_get(_device_rows(self.state.device_state, d)))
             chans = {}
             for name, nid in self.channel_map.names.items():
                 ch = nid % self.config.channels

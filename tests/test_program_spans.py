@@ -186,3 +186,112 @@ def test_step_hlo_carries_the_stage_scopes():
         assert f"/{scope}/" in text, scope
     # the scopes name ops; the program keeps the name it had
     assert "jit(<unknown>)/append/" in text
+
+
+# ---------------------------------------------------------- the SPMD engine
+SPMD_CFG = dict(CFG, device_capacity=64, token_capacity=128,
+                assignment_capacity=128)
+
+
+def test_engine_entry_point_spans_one_or_more_chips():
+    from sitewhere_tpu.parallel.sharded import SpmdEngine
+
+    one = Engine(EngineConfig(**CFG))
+    assert type(one) is Engine and one.config.shards == 1
+    eng = Engine(EngineConfig(**SPMD_CFG, shards=4))
+    assert type(eng) is SpmdEngine
+    assert eng.n_shards == eng.config.shards == eng.mesh.devices.size == 4
+    # the explicit count overrides the config's; the config records it
+    assert SpmdEngine(EngineConfig(**SPMD_CFG, shards=4),
+                      n_shards=2).config.shards == 2
+    assert SpmdEngine(EngineConfig(**SPMD_CFG),
+                      n_shards=2).config.shards == 2
+    assert SpmdEngine(EngineConfig(**SPMD_CFG, shards=2)).n_shards == 2
+    with pytest.raises(ValueError, match="devices"):
+        Engine(EngineConfig(**SPMD_CFG, shards=len(jax.devices()) + 1))
+
+
+@pytest.fixture(scope="module")
+def traced_spmd(tmp_path_factory):
+    """Frames of twice a lane ingested by a four-shard engine under the
+    profiler, lanes overflowing mid-batch: (spans, flight records)."""
+    from jax.profiler import ProfileData
+
+    tmp = tmp_path_factory.mktemp("spmd_spans")
+    eng = Engine(EngineConfig(**SPMD_CFG, shards=4,
+                              wal_dir=str(tmp / "wal")))
+    eng.epoch.now_ms = lambda: 4242
+    frames = [sum(fr, []) for fr in zip(*[iter(_frames(8))] * 2)]
+    _drive(eng, frames[:1])      # compile outside the trace
+    with jax.profiler.trace(str(tmp / "trace")):
+        for fr in frames[1:]:
+            eng.ingest_json_batch(fr)
+        eng.barrier()
+        eng.maybe_flush()
+    path, = glob.glob(str(tmp / "trace" / "**" / "*.xplane.pb"),
+                      recursive=True)
+    pd = ProfileData.from_file(path)
+    spans = [(ev.name, ev.start_ns, ev.end_ns, dict(ev.stats))
+             for p in pd.planes if p.name.startswith("/host:")
+             for ln in p.lines for ev in ln.events
+             if ev.name.startswith("swtpu.")]
+    recs = [r for r in eng.recent_traces(16) if r["kind"] == "ingest"][:3]
+    yield spans, recs
+    eng.wal.close()
+
+
+def test_spmd_path_writes_the_spans(traced_spmd):
+    spans, recs = traced_spmd
+    names = {n for n, *_ in spans}
+    assert set(NESTED) | {"swtpu.ingest", "swtpu.ingest.route",
+                          "swtpu.flush"} <= names
+    roots = [s for s in spans if s[0] == "swtpu.ingest"]
+    assert len(roots) == 3 and all(r[3]["payloads"] == 2 * FRAME
+                                   for r in roots)
+    for child, parent in NESTED.items():
+        assert any(_inside(k, p) for k in spans if k[0] == child
+                   and k[3].get("depth", 1) > 0
+                   for p in spans if p[0] == parent), child
+    assert any(s[0] == "swtpu.step.wait" and s[3]["depth"] == 0
+               for s in spans)
+    # a batch's route passes stage all its rows, between commit's bounds
+    for root in roots:
+        commit, = [s for s in spans if s[0] == "swtpu.ingest.commit"
+                   and _inside(s, root)]
+        route = [s[3] for s in spans if s[0] == "swtpu.ingest.route"
+                 and _inside(s, commit)]
+        assert route and sum(r["rows"] for r in route) == \
+            commit[3]["staged"] == 2 * FRAME
+        assert all(r["lane_min"] <= r["lane_max"] <= r["rows"]
+                   for r in route)
+    # a lane of FRAME rows overflows within a batch: it dispatches
+    # between two route passes
+    assert any(len([s for s in spans if s[0] == "swtpu.ingest.route"
+                    and _inside(s, root)]) > 1 for root in roots)
+    dispatch = [s[3] for s in spans if s[0] == "swtpu.step.dispatch"]
+    assert dispatch and all(d["shards"] == 4 for d in dispatch)
+    assert all(d["rows"] <= 4 * d["lane_max"] and d["lane_max"] <= FRAME
+               for d in dispatch)
+    for r in recs:
+        assert {"decode", "wal_append", "route", "commit", "wal_durable",
+                "dispatch"} <= set(r["stagesUs"])
+
+
+def test_spmd_step_has_a_stable_program_name():
+    """The sharded step's program is named for the trace's readers
+    (``benchmark/metrics/spmd_step_ms.py``)."""
+    from sitewhere_tpu.parallel.mesh import make_mesh
+    from sitewhere_tpu.parallel.sharded import (_make_spmd_scan_step,
+                                                _make_spmd_step,
+                                                create_stacked_state)
+
+    mesh = make_mesh(2)
+    cfg = PipelineConfig(auto_register=True)
+    state = create_stacked_state(mesh, 16, 32, 32, 64)
+    batch = jax.tree_util.tree_map(lambda x: jax.numpy.stack([x, x]),
+                                   EventBatch.zeros(8))
+    text = _make_spmd_step(mesh, cfg).lower(state, batch).as_text()
+    assert "module @jit_spmd_pipeline_step" in text
+    text = _make_spmd_scan_step(mesh, cfg, 4, 2).lower(state,
+                                                       batch).as_text()
+    assert "module @jit_spmd_scan_step" in text
