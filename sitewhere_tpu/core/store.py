@@ -7,7 +7,8 @@ selected by configuration/providers/TimeSeriesProvider.java) — one network
 write per event (EventPersistenceMapper.java:61-120, "hot loop #2").
 
 Here persistence is a batched append into a fixed-capacity HBM ring:
-one dynamic_update_slice per batch, no per-event work. The ring carries a
+each batch writes a contiguous window of ring rows per arena (two where
+it wraps) with dynamic_update_slice, no per-event work (ops/persist.py). The ring carries a
 tenant lane (logical multi-tenant isolation, like the per-tenant Influx
 databases) and a monotonically increasing 64-bit-equivalent write cursor
 (epoch:int32 + offset), so the host can compute durable watermarks for the

@@ -10,6 +10,7 @@ over HBM) costs no chip time. A pass is a compile, never a chip run.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -96,3 +97,40 @@ def test_flash_attention_kernel_compiles(one_chip):
         flash_attention_pallas, causal=True)).lower(x, x, x).compile()
     _report("flash_attention", compiled)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_store_append_writes_in_place(one_chip):
+    """The store append at deployment size (2^22 rows, 16,384 × 4 expanded
+    rows) writes its ring windows in place: one whole-column copy of
+    ``values`` alone would be 134 MB of temporaries, a version that copied
+    every column took 2.2 GB. Copies that reuse one buffer column after
+    column stay under that bound, so look for them by shape too."""
+    from sitewhere_tpu.core.registry import MAX_ACTIVE_ASSIGNMENTS
+    from sitewhere_tpu.core.store import EventStore
+    from sitewhere_tpu.core.types import AUX_LANES
+    from sitewhere_tpu.engine import EngineConfig
+    from sitewhere_tpu.ops.persist import append_events
+
+    channels = EngineConfig().channels
+    e = BATCH * MAX_ACTIVE_ASSIGNMENTS
+    store = jax.eval_shape(functools.partial(
+        EventStore.zeros, DEPLOYMENT["store_capacity"], channels))
+    i32 = jax.ShapeDtypeStruct((e,), jnp.int32)
+    rows = dict(
+        valid=jax.ShapeDtypeStruct((e,), jnp.bool_),
+        values=jax.ShapeDtypeStruct((e, channels), jnp.float32),
+        vmask=jax.ShapeDtypeStruct((e, channels), jnp.bool_),
+        aux=jax.ShapeDtypeStruct((e, AUX_LANES), jnp.int32),
+        **{k: i32 for k in ("etype", "device", "assignment", "tenant",
+                            "area", "customer", "asset", "ts_ms",
+                            "received_ms")})
+    compiled = jax.jit(append_events, donate_argnums=0).lower(
+        _placed(store, one_chip), **_placed(rows, one_chip)).compile()
+    mem = _report("append_events", compiled)
+    assert mem.temp_size_in_bytes < 256 * 2**20
+    assert mem.alias_size_in_bytes > 0
+    rows_s = DEPLOYMENT["store_capacity"]
+    column_copies = [
+        line for line in compiled.as_text().splitlines()
+        if re.search(rf"= \S+\[{rows_s}[,\]].* copy(-start)?\(", line)]
+    assert column_copies == []
