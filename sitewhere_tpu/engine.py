@@ -272,7 +272,7 @@ class IngestHostMixin:
         time: the per-tenant ``swtpu_ingest_e2e_seconds`` histograms are
         built entirely from flight records, so the ingest hot path pays
         ZERO extra device syncs for SLO latency — the same harvest rule
-        bench.py's cluster leg and the autotuner's stage medians ride."""
+        the autotuner's stage medians ride."""
         return self.flight.harvest_completed("ingest",
                                              terminal="device_ready")
 
@@ -381,7 +381,7 @@ class IngestHostMixin:
             # zero-copy path: the native scanner fills the staging arena
             # directly — no decode output arrays, no staging copy. Decode
             # runs UNDER the lock (the arena is shared mutable state);
-            # cross-thread decode parallelism is the worker pool's job.
+            # the sharded decoder fans the scan out across threads.
             with gate_ctx:
                 return self._ingest_batch_arena(payloads, tenant, tag, dec,
                                                 binary)
@@ -740,9 +740,8 @@ class EngineConfig:
                                        # Each arena holds
                                        # batch_capacity * scan_chunk rows
     flight_recorder: bool = True       # batch-lifecycle flight recorder
-                                       # (utils/flight.py); overhead is a
-                                       # few dict writes per BATCH — bench
-                                       # gates it at <= 3% of host e2e
+                                       # (utils/flight.py); a few dict
+                                       # writes per BATCH
     flight_capacity: int = 1024        # lifecycle records retained
     span_trace: bool = True            # hierarchical span tracer (ISSUE
                                        # 10, utils/tracing.SpanTracer):
@@ -751,8 +750,7 @@ class EngineConfig:
                                        # decode, query rounds, scheduler
                                        # fires; ingest lifecycle spans
                                        # derive from flight records at
-                                       # export — bench hard-gates the
-                                       # on-vs-off delta <= 3%
+                                       # export
     span_capacity: int = 4096          # completed spans retained
     span_sample: float = 1.0           # head-based keep fraction (seeded
                                        # deterministic per trace id);
@@ -817,18 +815,14 @@ class EngineConfig:
                                        # 11, utils/devicewatch.py): XLA
                                        # compile/retrace watchdog over
                                        # every program family, memory
-                                       # ledger, per-program cost —
-                                       # bench hard-gates the on-vs-off
-                                       # delta <= 3% and zero excess
-                                       # retraces across the smoke run
+                                       # ledger, per-program cost
     conservation: bool = True          # event conservation ledger
                                        # (ISSUE 14, utils/conservation):
                                        # per-stage flow counters the
                                        # audit plane balances against
                                        # the device counters; cost is
                                        # one dict add per batch + one
-                                       # np.sum per dispatch — bench
-                                       # hard-gates the delta <= 3%
+                                       # np.sum per dispatch
     shards: int = 1                    # chips the device plane spans:
                                        # Engine(config) with shards > 1
                                        # is the SPMD engine over that
@@ -1441,9 +1435,8 @@ class Engine(IngestHostMixin):
         c = self.config
         self.epoch = EpochBase()
         self.lock = threading.RLock()
-        # host-side auxiliary counters merged into metrics() — e.g. the
-        # DecodeWorkerPool's ambiguous-lane fallback count (VERDICT r3:
-        # the exactness fallback must be visible, not just a log line)
+        # host-side auxiliary counters merged into metrics() (arena and
+        # copy-staged rows, rule/analytics alerts, spilled rollups)
         self.host_counters: dict[str, int] = {}
         # the native host data-plane (C++ decode + interning) is the default;
         # pure-Python fallback when no compiler is available
@@ -1987,8 +1980,8 @@ class Engine(IngestHostMixin):
 
     def _ingest_decoded_arena(self, res, payloads, tenant,
                               reg_decoder) -> dict:
-        """Stage an externally decoded SoA batch (the worker pool's
-        shared-memory outputs, or the in-process fallback) through the
+        """Stage a batch decoded outside the arena (the strict and
+        lenient native paths decode to SoA columns first) through the
         arena path: ONE vectorized copy of the decode columns into the
         fill arena, then the shared commit — no DecodedArrays copies, no
         HostEventBuffer, no emit-time reallocation. Caller has already
@@ -2203,8 +2196,8 @@ class Engine(IngestHostMixin):
             if self._buf.full:
                 self.flush_async()
             self.channel_map.collisions += res.collisions
-            # rows that took the copy-staging path (bench reports these
-            # per batch to prove the arena path stays copy-free)
+            # rows that took the copy-staging path (the arena path
+            # leaves this at zero; tests pin it)
             self.host_counters["staged_copy_rows"] = \
                 self.host_counters.get("staged_copy_rows", 0) + staged
             self.ledger.add("staged_rows", staged)

@@ -138,9 +138,8 @@ class NativeBatchDecoder:
                       *, binary: bool = False) -> tuple[int, int]:
         """One scanner call over an already-concatenated wire batch
         (``offsets`` int64[>=n+1]; output arrays sized >= n rows). THE
-        single marshalling site for swtpu_decode_*_batch — the worker
-        pool's shared-memory views and the bench's preallocated arrays
-        go through here too, so a signature change has one home.
+        single marshalling site for swtpu_decode_*_batch, so a signature
+        change has one home.
         Returns (n_ok, channel_collisions)."""
         collisions = ctypes.c_int32(0)
 

@@ -1,44 +1,24 @@
-"""Multi-worker host ingest: N decode/staging processes feed one engine.
+"""Multi-core host decode: one wire batch split across decode threads.
 
 SURVEY.md §2.9 maps the reference's replica parallelism (each microservice
 scales horizontally behind partitioned Kafka consumer groups) to "multiple
-host ingest workers feeding a fixed chip mesh". The single-process ingest
-path tops out at one core's JSON-scan rate; this pool runs the C++ scanner
-(native/src/swtpu.cpp) in ``n_workers`` separate processes, each decoding
-wire batches into SHARED-MEMORY SoA staging, with the engine process only
-translating dictionary ids and dispatching device programs.
-
-Dictionary federation (the crux): each worker owns LOCAL interners for
-device tokens / measurement names / alert types (interner state cannot be
-shared across processes). Workers report newly-interned strings once, the
-engine maintains per-worker translation tables, and steady-state batches
-translate with pure numpy gathers — no per-event Python, no string traffic.
-Measurement names additionally need a LANE permutation (a name's value
-lands in lane ``name_id % channels``, and worker name ids diverge from the
-engine's); if a worker's lane mapping ever becomes ambiguous (same worker
-lane claimed by names that map to different engine lanes — requires an
-in-worker lane collision, which the single-path decoder also mishandles
-only by aliasing) the pool falls back to engine-side decode for that
-worker's batches, trading speed for exactness.
-
-Workers never import jax; the engine process keeps sole ownership of the
-device. On a 1-core host the pool degrades to a single worker and roughly
-matches the in-process path; with spare cores the scan work scales out.
+host ingest workers feeding a fixed chip mesh". The single-threaded
+ingest path tops out at one core's JSON-scan rate; the
+:class:`ShardedArenaDecoder` below cuts each wire batch by payload bytes
+and runs the C++ scanner (native/src/swtpu.cpp) on every slice in its own
+thread, straight into disjoint rows of the engine's fill arena. The
+native scans release the GIL, so the slices decode in parallel; the
+engine sizes the fan-out with ``EngineConfig.ingest_workers``.
 """
 
 from __future__ import annotations
 
 import ctypes
-import logging
-import multiprocessing as mp
 import os
 import threading
 import time
-from multiprocessing import shared_memory
 
 import numpy as np
-
-logger = logging.getLogger(__name__)
 
 # One process-wide thread pool behind every engine's sharded decode:
 # shard scans release the GIL inside the native call, so the threads are
@@ -59,138 +39,6 @@ def _shard_executor():
                     max_workers=max(1, (os.cpu_count() or 2) - 1),
                     thread_name_prefix="swtpu-shard")
     return _shard_pool
-
-_HDR = 8  # int64 header slots in shm_in: [n_msgs, buf_len, ...reserved]
-
-
-def _shm_arrays(buf, max_msgs: int, channels: int):
-    """Carve the output SoA views out of one shared-memory block."""
-    b, c = max_msgs, channels
-    off = 0
-
-    def take(dtype, shape):
-        nonlocal off
-        n = int(np.prod(shape)) * np.dtype(dtype).itemsize
-        a = np.ndarray(shape, dtype, buffer=buf, offset=off)
-        off += n
-        return a
-
-    return {
-        "rtype": take(np.int32, (b,)),
-        "token": take(np.int32, (b,)),
-        "ts": take(np.int64, (b,)),
-        "values": take(np.float32, (b, c)),
-        "chmask": take(np.uint8, (b, c)),
-        "aux0": take(np.int32, (b,)),
-        "aux1": take(np.int32, (b,)),
-        "level": take(np.int32, (b,)),
-    }
-
-
-def _out_bytes(max_msgs: int, channels: int) -> int:
-    return max_msgs * (4 + 4 + 8 + 4 * channels + channels + 4 + 4 + 4)
-
-
-def _worker_main(conn, in_name: str, out_name: str, max_msgs: int,
-                 max_bytes: int, channels: int, token_capacity: int) -> None:
-    """One decode worker: wire batch in shm_in -> SoA in shm_out.
-    Replies ("done", n_ok, collisions, new_tokens, new_names, new_alerts)
-    where the new_* lists carry strings interned FOR THE FIRST TIME by this
-    batch, in local-id order (the engine extends its translation tables
-    from exactly these)."""
-    from sitewhere_tpu.ingest.fast_decode import NativeBatchDecoder
-    from sitewhere_tpu.native.binding import NativeInterner
-
-    shm_in = shared_memory.SharedMemory(name=in_name)
-    shm_out = shared_memory.SharedMemory(name=out_name)
-    try:
-        hdr = np.ndarray((_HDR,), np.int64, buffer=shm_in.buf)
-        offsets = np.ndarray((max_msgs + 1,), np.int64, buffer=shm_in.buf,
-                             offset=_HDR * 8)
-        data_off = _HDR * 8 + (max_msgs + 1) * 8
-        out = _shm_arrays(shm_out.buf, max_msgs, channels)
-
-        tokens = NativeInterner(token_capacity)
-        dec = NativeBatchDecoder(tokens, channels)
-        n_tok = n_name = n_alert = n_eid = 0
-
-        def tail(interner, since: int) -> list[str]:
-            return [interner.token(i) for i in range(since, len(interner))]
-
-        while True:
-            msg = conn.recv()
-            if msg is None:
-                break
-            n = int(hdr[0])
-            payloads_buf = bytes(shm_in.buf[data_off:data_off + int(hdr[1])])
-            # one scanner call over the whole batch, straight into shm
-            n_ok, collisions = dec.decode_packed(
-                payloads_buf, offsets, n, out["rtype"], out["token"],
-                out["ts"], out["values"], out["chmask"], out["aux0"],
-                out["aux1"], out["level"])
-            new_tokens = tail(tokens, n_tok)
-            new_names = tail(dec.names, n_name)
-            new_alerts = tail(dec.alert_types, n_alert)
-            new_eids = tail(dec.event_ids, n_eid)
-            n_tok += len(new_tokens)
-            n_name += len(new_names)
-            n_alert += len(new_alerts)
-            n_eid += len(new_eids)
-            conn.send(("done", n_ok, collisions,
-                       new_tokens, new_names, new_alerts, new_eids))
-    finally:
-        shm_in.close()
-        shm_out.close()
-        conn.close()
-
-
-class _Worker:
-    def __init__(self, idx: int, max_msgs: int, max_bytes: int,
-                 channels: int, token_capacity: int, ctx):
-        in_bytes = _HDR * 8 + (max_msgs + 1) * 8 + max_bytes
-        self.shm_in = shared_memory.SharedMemory(
-            create=True, size=in_bytes)
-        self.shm_out = shared_memory.SharedMemory(
-            create=True, size=_out_bytes(max_msgs, channels))
-        self.hdr = np.ndarray((_HDR,), np.int64, buffer=self.shm_in.buf)
-        self.offsets = np.ndarray((max_msgs + 1,), np.int64,
-                                  buffer=self.shm_in.buf, offset=_HDR * 8)
-        self.data_off = _HDR * 8 + (max_msgs + 1) * 8
-        self.out = _shm_arrays(self.shm_out.buf, max_msgs, channels)
-        self.conn, child = ctx.Pipe()
-        self.proc = ctx.Process(
-            target=_worker_main,
-            args=(child, self.shm_in.name, self.shm_out.name, max_msgs,
-                  max_bytes, channels, token_capacity),
-            daemon=True)
-        self.proc.start()
-        child.close()
-        # engine-side translation state
-        self.tok_map = np.empty(0, np.int32)
-        self.alert_map = np.empty(0, np.int32)
-        self.eid_map = np.empty(0, np.int32)   # worker alt-id -> engine id
-        self.lane_owner: dict[int, int] = {}   # worker lane -> engine lane
-        self.elane_owner: dict[int, int] = {}  # engine lane -> worker lane
-        self.n_names_seen = 0   # dense worker-local name ids handed out
-        self.lane_conflict = False
-        self.pending: tuple[list[bytes], str] | None = None
-
-    def close(self) -> None:
-        try:
-            self.conn.send(None)
-        except (BrokenPipeError, OSError):
-            pass
-        self.proc.join(timeout=5)
-        if self.proc.is_alive():
-            self.proc.kill()
-            self.proc.join(timeout=5)
-        self.conn.close()
-        for shm in (self.shm_in, self.shm_out):
-            shm.close()
-            try:
-                shm.unlink()
-            except FileNotFoundError:
-                pass
 
 
 class ShardedArenaDecoder:
@@ -419,233 +267,3 @@ class ShardedArenaDecoder:
         for ctx in self._ctxs:
             self.lib.swtpu_shard_destroy(ctx)
         self._ctxs = []
-
-
-class DecodeWorkerPool:
-    """Round-robin pool of decode workers in front of one engine.
-
-    ``submit()`` hands a wire batch to the next worker and returns
-    immediately (absorbing that worker's previous batch first if still
-    outstanding); ``flush()`` absorbs everything. Ingest summaries come
-    back from the absorb step with the same shape as
-    ``engine.ingest_json_batch``."""
-
-    def __init__(self, engine, n_workers: int | None = None,
-                 max_msgs: int | None = None, max_bytes: int = 1 << 24):
-        from sitewhere_tpu.ingest.fast_decode import native_available
-
-        if not native_available():
-            raise RuntimeError("native library unavailable")
-        if engine.config.strict_channels:
-            # the strict contract (reject + roll back a batch that would
-            # exceed channel capacity, engine._check_strict_native) cannot
-            # be enforced from worker-local interners — a colliding batch
-            # would be WAL-logged and staged before the engine could see
-            # the collision. Refuse loudly instead of silently degrading.
-            raise ValueError(
-                "DecodeWorkerPool does not support strict_channels engines;"
-                " use the in-process ingest path")
-        self.engine = engine
-        self.channels = engine.config.channels
-        self.n_workers = n_workers or max(1, (os.cpu_count() or 1) - 1)
-        self.max_msgs = max_msgs or max(16384, engine.config.batch_capacity)
-        self.max_bytes = max_bytes
-        ctx = mp.get_context("spawn")   # workers must not inherit jax state
-        self.workers = [
-            _Worker(i, self.max_msgs, max_bytes, self.channels,
-                    engine.config.token_capacity, ctx)
-            for i in range(self.n_workers)
-        ]
-        self._next = 0
-        self.summaries: list[dict] = []
-        self.fallback_batches = 0
-
-    # ------------------------------------------------------------ engine side
-    def _absorb(self, w: _Worker) -> dict | None:
-        if w.pending is None:
-            return None
-        payloads, tenant = w.pending
-        w.pending = None
-        kind, n_ok, collisions, new_tokens, new_names, new_alerts, \
-            new_eids = w.conn.recv()
-        assert kind == "done"
-        eng = self.engine
-        # ---- extend translation tables from first-seen strings ----------
-        # Under eng.lock: these interners are shared with REST registration
-        # and in-process ingest, which all intern under the same lock.
-        with eng.lock:
-            if new_tokens:
-                w.tok_map = np.concatenate([
-                    w.tok_map,
-                    np.fromiter((eng.tokens.intern(t) for t in new_tokens),
-                                np.int32, len(new_tokens))])
-            if new_alerts:
-                w.alert_map = np.concatenate([
-                    w.alert_map,
-                    np.fromiter(
-                        (eng.alert_types.intern(t) for t in new_alerts),
-                        np.int32, len(new_alerts))])
-            if new_eids:
-                w.eid_map = np.concatenate([
-                    w.eid_map,
-                    np.fromiter(
-                        (eng.event_ids.intern(t) for t in new_eids),
-                        np.int32, len(new_eids))])
-            if new_names:
-                names_interner = (eng._native_decoder.names
-                                  if eng._native_decoder else None)
-                for name in new_names:
-                    wid = w.n_names_seen   # dense worker-local name id order
-                    w.n_names_seen += 1
-                    eid = (names_interner.intern(name) if names_interner
-                           else eng.channel_map.names.intern(name))
-                    wlane, elane = wid % self.channels, eid % self.channels
-                    prev = w.lane_owner.get(wlane)
-                    if prev is None:
-                        # the engine lane must not already belong to a
-                        # DIFFERENT worker lane — a non-injective map would
-                        # let one lane's scatter clobber the other's
-                        # (silent data loss)
-                        if w.elane_owner.get(elane, wlane) != wlane:
-                            w.lane_conflict = True
-                        w.lane_owner[wlane] = elane
-                        w.elane_owner[elane] = wlane
-                    elif prev != elane:
-                        w.lane_conflict = True
-        n = len(payloads)
-        if w.lane_conflict:
-            # ambiguous lane permutation: exactness over speed — decode
-            # this worker's batches in-engine from the raw payloads.
-            # Surfaced as an engine metric so operators see the pool
-            # degrading, not just a log line (VERDICT r3 weak #1)
-            self.fallback_batches += 1
-            with eng.lock:
-                eng.host_counters["worker_fallback_batches"] = \
-                    eng.host_counters.get("worker_fallback_batches", 0) + 1
-            return eng.ingest_json_batch(payloads, tenant=tenant)
-        # ---- translate + stage (numpy gathers only) ---------------------
-        from sitewhere_tpu.engine import WAL_JSON
-        from sitewhere_tpu.ingest.decoders import JsonDeviceRequestDecoder
-        from sitewhere_tpu.ingest.fast_decode import (RT_ALERT,
-                                                      RT_MEASUREMENT,
-                                                      DecodedArrays)
-
-        # shm views, NOT copies: the engine's staging (arena copy or
-        # legacy buffer slices) completes synchronously inside
-        # _ingest_decoded below, before this worker can be handed its
-        # next batch — so the worker never overwrites a view in use.
-        o = w.out
-        rtype = o["rtype"][:n]
-        token = o["token"][:n]
-        gtok = (w.tok_map[np.clip(token, 0, max(0, len(w.tok_map) - 1))]
-                if len(w.tok_map) else np.full(n, -1, np.int32))
-        gtok = np.where(rtype >= 0, gtok, -1).astype(np.int32)
-        # scatter ONLY lanes that carry data (every data-carrying worker
-        # lane has a name behind it, hence an entry in lane_owner);
-        # unmapped lanes must never overwrite a mapped engine lane
-        if all(wl == el for wl, el in w.lane_owner.items()):
-            values = o["values"][:n]
-            chmask = o["chmask"][:n].astype(bool)
-        else:
-            wl = np.fromiter(w.lane_owner.keys(), np.int64,
-                             len(w.lane_owner))
-            el = np.fromiter(w.lane_owner.values(), np.int64,
-                             len(w.lane_owner))
-            raw_v = o["values"][:n]
-            raw_m = o["chmask"][:n].astype(bool)
-            values = np.zeros((n, self.channels), np.float32)
-            chmask = np.zeros((n, self.channels), bool)
-            values[:, el] = raw_v[:, wl]
-            chmask[:, el] = raw_m[:, wl]
-            # the lane permutation is derived from measurement names only;
-            # LOCATION rows carry lat/lon/elev in FIXED lanes 0-2 (see
-            # swtpu.cpp scan_location) and other non-measurement rows use
-            # raw lanes — keep their lanes untouched
-            nonmeas = rtype != RT_MEASUREMENT
-            if np.any(nonmeas):
-                values[nonmeas] = raw_v[nonmeas]
-                chmask[nonmeas] = raw_m[nonmeas]
-        aux0 = o["aux0"][:n]
-        alert_rows = rtype == RT_ALERT
-        if np.any(alert_rows) and len(w.alert_map):
-            # in-place alert-type translation on the shm view is safe:
-            # this slot is dead until the worker's next batch overwrites it
-            aux0[alert_rows] = w.alert_map[
-                np.clip(aux0[alert_rows], 0, len(w.alert_map) - 1)]
-        aux1 = o["aux1"][:n]
-        alt_rows = aux1 >= 0
-        if np.any(alt_rows) and len(w.eid_map):
-            aux1[alt_rows] = w.eid_map[
-                np.clip(aux1[alt_rows], 0, len(w.eid_map) - 1)]
-        res = DecodedArrays(
-            n_ok=int(np.sum(rtype >= 0)), rtype=rtype, token_id=gtok,
-            ts_ms64=o["ts"][:n], values=values, chmask=chmask,
-            aux0=aux0, aux1=aux1, level=o["level"][:n],
-            collisions=collisions)
-        with eng.lock:
-            eng._wal_append(WAL_JSON, payloads, tenant)
-            # _ingest_decoded routes through the engine's staging arenas
-            # when they exist: ONE vectorized shm->arena copy replaces
-            # the DecodedArrays copies + HostEventBuffer staging pass.
-            # On an SpmdEngine the same seam scatters the shm columns
-            # into the stacked per-shard arena lanes instead.
-            return eng._ingest_decoded(res, payloads, tenant,
-                                       JsonDeviceRequestDecoder())
-
-    def submit(self, payloads: list[bytes], tenant: str = "default") -> None:
-        """Queue one wire batch on the next worker (absorbs that worker's
-        outstanding batch first, so at most one batch is in flight per
-        worker)."""
-        w = self.workers[self._next]
-        self._next = (self._next + 1) % self.n_workers
-        s = self._absorb(w)
-        if s is not None:
-            self.summaries.append(s)
-        n = len(payloads)
-        if n > self.max_msgs:
-            raise ValueError(f"batch of {n} exceeds max_msgs {self.max_msgs}")
-        lens = np.fromiter((len(p) for p in payloads), np.int64, n)
-        total = int(lens.sum())
-        if total > self.max_bytes:
-            raise ValueError(
-                f"batch of {total} payload bytes exceeds the pool's "
-                f"max_bytes {self.max_bytes}; raise max_bytes or split "
-                "the batch")
-        self.offsets_fill(w, lens)
-        buf = b"".join(payloads)
-        w.shm_in.buf[w.data_off:w.data_off + len(buf)] = buf
-        w.hdr[0], w.hdr[1] = n, len(buf)
-        w.pending = (payloads, tenant)
-        w.conn.send(("decode",))
-
-    @staticmethod
-    def offsets_fill(w: _Worker, lens: np.ndarray) -> None:
-        w.offsets[0] = 0
-        np.cumsum(lens, out=w.offsets[1:1 + len(lens)])
-
-    def flush(self) -> list[dict]:
-        """Absorb every outstanding batch; returns their summaries."""
-        out, self.summaries = self.summaries, []
-        for w in self.workers:
-            s = self._absorb(w)
-            if s is not None:
-                out.append(s)
-        return out
-
-    def stats(self) -> dict:
-        return {
-            "n_workers": self.n_workers,
-            "fallback_batches": self.fallback_batches,
-            "lane_conflicts": sum(1 for w in self.workers if w.lane_conflict),
-        }
-
-    def close(self) -> None:
-        self.flush()
-        for w in self.workers:
-            w.close()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        self.close()

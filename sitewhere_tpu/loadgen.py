@@ -411,7 +411,7 @@ def build_open_loop_schedule(spec: OpenLoopSpec) -> list[ScheduledOp]:
 def schedule_fingerprint(schedule: list[ScheduledOp]) -> str:
     """SHA-256 over the canonical byte form of a schedule — the
     determinism pin (same seed => same fingerprint) and the provenance
-    field the bench records next to its measured numbers."""
+    field a run records next to its measured numbers."""
     h = hashlib.sha256()
     for op in schedule:
         h.update(f"{op.kind}|{op.tenant}|{op.t_s!r}\n".encode())
@@ -619,8 +619,7 @@ def run_open_loop(engine, schedule: list[ScheduledOp], *,
     wall = time.perf_counter() - t0
     # span/trace coverage (ISSUE 10): every sampled ingest trace id must
     # still resolve to a non-empty timeline (flight-record intervals or
-    # live spans) — the observability plane's own SLO, reported by the
-    # bench cluster leg
+    # live spans) — the observability plane's own SLO
     coverage = None
     get_tl = getattr(engine, "get_trace_timeline", None)
     if trace_sample and get_tl is not None:
@@ -670,145 +669,6 @@ def run_open_loop(engine, schedule: list[ScheduledOp], *,
         per_tenant=per_tenant, shed_events=sum(shed.values()),
         trace_coverage=coverage, compile_counts=compile_counts,
         ingest_path=ingest_path)
-
-
-# ---------------------------------------------------------------------------
-# Persistent-connection wire mode (ISSUE 20).
-# ---------------------------------------------------------------------------
-@dataclasses.dataclass(frozen=True)
-class WireLoadSpec:
-    """Seed-determined description of a connection-holding load: N live
-    connections, each carrying its own deterministic frame list. Same
-    spec + same seed => byte-identical frames per connection (the
-    ``build_open_loop_schedule`` fingerprint discipline; existing
-    open-loop schedules are untouched by this mode)."""
-
-    n_connections: int = 1000
-    frames_per_conn: int = 10
-    n_devices: int = 256
-    tenant: str = "default"
-    device_prefix: str = "wl-dev"
-    seed: int = 0
-
-
-def build_wire_schedule(spec: WireLoadSpec) -> list[list[bytes]]:
-    """Per-connection payload lists — a pure function of the spec (each
-    connection draws from its own seeded stream, so connection counts can
-    change without disturbing other connections' frames)."""
-    out: list[list[bytes]] = []
-    for c in range(spec.n_connections):
-        rng = np.random.default_rng([spec.seed, c])
-        picks = rng.integers(0, spec.n_devices, spec.frames_per_conn)
-        out.append([
-            generate_measurements_message(
-                f"{spec.device_prefix}-{int(d)}", c * 1_000_000 + i)
-            for i, d in enumerate(picks)
-        ])
-    return out
-
-
-def wire_schedule_fingerprint(payload_lists: list[list[bytes]]) -> str:
-    """SHA-256 over the canonical byte form — the determinism pin the
-    bench records next to its measured wire numbers."""
-    h = hashlib.sha256()
-    for i, frames in enumerate(payload_lists):
-        h.update(f"conn|{i}|{len(frames)}\n".encode())
-        for p in frames:
-            h.update(p)
-    return h.hexdigest()
-
-
-def _rss_bytes() -> int:
-    try:
-        with open("/proc/self/status") as f:
-            for line in f:
-                if line.startswith("VmRSS:"):
-                    return int(line.split()[1]) * 1024
-    except OSError:
-        pass
-    import resource
-
-    return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
-
-
-@dataclasses.dataclass
-class WireLoadResult:
-    """One connection-holding run against a live wire edge. Connections
-    stay OPEN for the whole run — ``per_connection_bytes`` is the RSS
-    delta from before the connect wave to all-connected, divided by the
-    connection count (client and server share the process in the bench,
-    so the figure covers both ends of each connection)."""
-
-    connections: int
-    events: int
-    acked: int
-    wall_s: float
-    events_per_s: float
-    connect_s: float
-    per_connection_bytes: float
-    publish_p50_ms: float | None
-    publish_p99_ms: float | None
-
-    def to_dict(self) -> dict:
-        return dataclasses.asdict(self)
-
-
-async def run_wire_load(host: str, port: int,
-                        payload_lists: list[list[bytes]], *,
-                        tenant: str = "default", qos: int = 1,
-                        connect_wave: int = 100,
-                        client_id_prefix: str = "wl") -> WireLoadResult:
-    """Hold ``len(payload_lists)`` live MQTT connections against a wire
-    edge and publish each connection's frames (QoS 1 by default: every
-    publish awaits its WAL-durable PUBACK). Connections open in waves of
-    ``connect_wave`` to keep the accept queue shallow, then ALL of them
-    stay open while frames interleave across the full set — the
-    persistent-connection contrast to one-request-per-event drivers."""
-    from sitewhere_tpu.ingest.mqtt import MqttClient
-
-    rss0 = _rss_bytes()
-    t_conn = time.perf_counter()
-    clients: list[MqttClient] = []
-    for lo in range(0, len(payload_lists), connect_wave):
-        wave = []
-        for i in range(lo, min(lo + connect_wave, len(payload_lists))):
-            c = MqttClient(host, port, client_id=f"{client_id_prefix}-{i}",
-                           keepalive=0)
-            clients.append(c)
-            wave.append(c.connect())
-        await asyncio.gather(*wave)
-    connect_s = time.perf_counter() - t_conn
-    per_conn = ((_rss_bytes() - rss0) / len(clients)) if clients else 0.0
-
-    topic = f"swtpu/{tenant}/events"
-    lat: list[float] = []
-    acked = 0
-
-    async def one_conn(c: MqttClient, frames: list[bytes]) -> None:
-        nonlocal acked
-        for p in frames:
-            s0 = time.perf_counter()
-            await asyncio.wait_for(c.publish(topic, p, qos=qos), 60)
-            lat.append((time.perf_counter() - s0) * 1e3)
-            if qos:
-                acked += 1
-
-    t0 = time.perf_counter()
-    await asyncio.gather(*(one_conn(c, f)
-                           for c, f in zip(clients, payload_lists)))
-    wall = time.perf_counter() - t0
-    await asyncio.gather(*(c.disconnect() for c in clients),
-                         return_exceptions=True)
-    events = sum(len(f) for f in payload_lists)
-    pct = _pcts(lat)
-    return WireLoadResult(
-        connections=len(clients), events=events,
-        acked=acked if qos else events,
-        wall_s=round(wall, 3),
-        events_per_s=round(events / wall, 1) if wall else 0.0,
-        connect_s=round(connect_s, 3),
-        per_connection_bytes=round(per_conn, 1),
-        publish_p50_ms=pct["p50_ms"], publish_p99_ms=pct["p99_ms"])
 
 
 async def run_rest_load(base_url: str, jwt: str, n_workers: int = 5,

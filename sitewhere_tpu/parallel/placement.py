@@ -71,8 +71,7 @@ target rank; coordinated from any rank)
    its fences after the tail ship so an expiry mid-ship can never
    commit.
 
-Crash matrix (chaos-gated in tests/test_placement.py and the bench
-placement leg): source killed -> coordinator aborts, map unchanged,
+Crash matrix (chaos-gated in tests/test_placement.py): source killed -> coordinator aborts, map unchanged,
 target's partial copy is invisible (reads filter to owned slots);
 target killed -> catch-up RPC fails, abort, source still sole owner;
 coordinator killed pre-commit -> the fence deadline unfences the
@@ -277,9 +276,6 @@ class PlacementManager:
         # owned-slot filter arms only then, so the no-move fleet pays
         # nothing on the query path
         self.ever_moved = False
-        # the bench's overhead estimator toggles enforcement per frame;
-        # production never flips this
-        self.enforce = True
         self.counters = {"moves_started": 0, "moves_completed": 0,
                          "moves_aborted": 0, "fenced_write_redirects": 0,
                          "stale_sender_redirects": 0,
@@ -517,8 +513,6 @@ class PlacementManager:
         (process/admin paths): every token must hash into a slot this
         rank owns and is not fencing, else the whole call redirects
         BEFORE anything applies (all-or-nothing, like the QoS shed)."""
-        if not self.enforce:
-            return
         m = self.map()
         me = self.cluster.rank
         with self._lock:
@@ -543,8 +537,8 @@ class PlacementManager:
         exists to prevent). Unroutable payloads (slot < 0) pass: the
         engine's dead-letter path owns them wherever they land. Hot
         path: one native route call + one vectorized gather — no lock
-        unless a fence is up (the bench gates this <= 3%)."""
-        if not self.enforce or not payloads:
+        unless a fence is up."""
+        if not payloads:
             return
         import numpy as np
         from sitewhere_tpu.native.binding import route_payloads

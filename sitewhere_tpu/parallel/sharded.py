@@ -684,7 +684,7 @@ class SpmdEngine(Engine):
             raise ValueError(f"SpmdEngine over {n_shards} shards needs as "
                              f"many devices; JAX sees {n}")
         # arena=False keeps the per-row host router on the batch path —
-        # the byte-identity oracle and bench contrast baseline
+        # the byte-identity oracle the arena path is tested against
         self._spmd_arena = bool(arena) and cfg0.ingest_arenas >= 0
         # the interner spans every shard's tokens; everything else in the
         # base constructor is host machinery the SPMD engine keeps as-is
@@ -733,8 +733,7 @@ class SpmdEngine(Engine):
         # breakdown sums to the folded staging equations by
         # construction); the token->slot route mirror and the heat/skew
         # tracker carry the EXTRA accounting (slot bincount, dispatch
-        # skew note, staged HWM) that shard_heat.enabled toggles for the
-        # bench on/off overhead contrast
+        # skew note, staged HWM) that shard_heat.enabled toggles
         self._shard_rows_routed = np.zeros(n, np.int64)
         self._shard_rows_dispatched = np.zeros(n, np.int64)
         self._shard_staged_hwm = np.zeros(n, np.int64)

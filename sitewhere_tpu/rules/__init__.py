@@ -6,7 +6,7 @@ specs, validated and lowered to the device tables in ops/rules.py.
 ``manager`` — the host runtime: compile-before-swap installs, mtime
 hot-reload, dedup-keyed alert emission through the normal ingest
 pipeline, rollup reads. ``oracle`` — host-side reference semantics used
-by tests and the bench parity gates.
+by the tests as the parity reference.
 """
 
 from sitewhere_tpu.rules.manager import RuleSetWatcher, RulesManager

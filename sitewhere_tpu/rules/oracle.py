@@ -5,7 +5,7 @@ ops/rules.py, processing the event stream ONE EVENT AT A TIME (the
 finest possible batch partition). Because the device kernels are
 batch-partition invariant by construction, their fire-key sets and
 rollup tables must match this oracle exactly — that equivalence is what
-tests/test_rules.py pins and the bench rules leg hard-gates.
+tests/test_rules.py and tests/test_rules_replay.py pin.
 
 Events are dicts: ``{"ts": int_ms, "group": int, "value": float | None,
 "value_b": float | None}`` — ``group`` already resolved for the rule's

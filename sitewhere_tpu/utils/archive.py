@@ -618,7 +618,7 @@ class EventArchive:
         # not count them as lost rows
         self._gaps: dict[int, list[list[int]]] = {}
         # pushdown accounting (exported as swtpu_archive_* gauges at
-        # scrape time; the bench's pruning proof reads them directly)
+        # scrape time; the pruning tests read them directly)
         self.queries = 0            # pushdown query() calls
         self.plan_considered = 0    # segments the eviction cap admitted
         self.plan_pruned = 0        # ...of which zone maps/blooms pruned
@@ -1122,8 +1122,7 @@ class EventArchive:
         page winners are the only rows whose payload columns materialize.
         Results (total AND rows, ts-tie ordering included) are
         byte-identical to :meth:`query_unpruned`, the retained full-scan
-        reference — pinned by tests/test_archive_pushdown.py and the
-        smoke-bench archive gate.
+        reference — pinned by tests/test_archive_pushdown.py.
 
         ``max_pos[part]`` caps the scan at rows already EVICTED from that
         partition's ring (absolute position < max_pos) so ring + archive
@@ -1287,8 +1286,8 @@ class EventArchive:
         """The pre-pushdown full scan, kept VERBATIM as the parity oracle:
         decodes every eligible segment with its own ``np.load`` and
         filters row-by-row. :meth:`query` must return byte-identical
-        (total, rows) — the smoke bench hard-gates it and the pushdown
-        tests pin it across tie/bloom/gap edge cases."""
+        (total, rows) — the pushdown tests pin it across tie/bloom/gap
+        edge cases."""
         total = 0
         top: list[tuple[int, dict]] = []
         for seg in self.segments:

@@ -4,8 +4,8 @@ The PR-3 flight recorder already timestamps every batch's lifecycle
 (decode -> WAL -> commit -> dispatch -> device-ready) at near-zero cost;
 this controller closes the loop. Every ``interval`` dispatches it takes
 the MEDIAN per-stage durations over the recent record window
-(utils/flight.stage_durations — the same harvesting rule bench.py
-reports) and nudges ONE knob toward the dominant stage:
+(utils/flight.stage_durations) and nudges ONE knob toward the dominant
+stage:
 
   decode dominates      -> widen the sharded-decode worker fan-out
   device dominates      -> deepen ``dispatch_depth`` (host/device overlap)

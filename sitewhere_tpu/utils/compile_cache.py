@@ -1,9 +1,8 @@
 """Where the persistent XLA compile cache lives.
 
 Every entry point that runs on the chip calls :func:`configure_compile_cache`
-before its first compile: ``chip_smoke.py``, ``bench.py``,
-``scripts/bench_spmd.py``, ``python -m sitewhere_tpu.loadgen`` and
-``run_rank``. It is deliberately not called on ``import sitewhere_tpu`` or
+before its first compile: ``benchmark/harness.py``, ``chip_smoke.py``,
+``python -m sitewhere_tpu.loadgen`` and ``run_rank``. It is deliberately not called on ``import sitewhere_tpu`` or
 from the test harness.
 
 The cache key includes the directory, so the path is fixed: never built

@@ -29,8 +29,8 @@ this module makes loss continuously *measurable* in a live system:
     violations increment ``swtpu_conservation_violation_total`` and
     emit one loud structured log line.
 
-Import hygiene: this module must import with jax blocked (the offline
-bench tooling reads ledger documents); jax is imported lazily inside
+Import hygiene: this module must import with jax blocked (offline
+tooling reads ledger documents); jax is imported lazily inside
 the snapshot helpers only.
 
 Conservation equations (the contract future PRs must keep balanced):
@@ -129,8 +129,7 @@ class FlowLedger:
     """Host-side flow counters for the boundaries nothing else counts.
 
     All mutation sites hold the engine lock, so no lock of its own;
-    ``enabled`` toggles counting (the bench overhead estimator flips it
-    per batch). ``rebase`` records the device counters a restored
+    ``enabled`` toggles counting. ``rebase`` records the device counters a restored
     snapshot already carries, so a recovered engine's ledger balances
     over the rows IT staged (WAL replay), not the pre-crash history."""
 

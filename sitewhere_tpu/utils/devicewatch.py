@@ -352,8 +352,7 @@ class WatchedProgram:
 
 class DeviceWatch:
     """Process-global watchdog state (one XLA compile cache per process,
-    one watch). ``enabled`` gates ALL per-dispatch work — the bench
-    toggles it per batch for the overhead gate; ``strict`` turns budget
+    one watch). ``enabled`` gates ALL per-dispatch work; ``strict`` turns budget
     violations into :class:`RetraceError` (tests, CI)."""
 
     def __init__(self):
@@ -381,7 +380,7 @@ class DeviceWatch:
 
     # ----------------------------------------------------------- posture
     def compile_totals(self) -> dict[str, int]:
-        """family -> programs compiled so far (the loadgen/bench delta
+        """family -> programs compiled so far (the loadgen's delta
         source for "recompiles during this run")."""
         with self._lock:
             return {n: f.compiles for n, f in self._families.items()}

@@ -10,7 +10,7 @@ never pay more than one module-attribute read per call.
 Faults are keyed by (src_rank, dst_rank, method) and are DETERMINISTIC:
 a plan carries a seed, and probabilistic rules draw from one
 ``random.Random(seed)`` stream, so a failing chaos run replays exactly
-with the same seed (the property BENCH/chaos logs record).
+with the same seed.
 
 Supported rules:
 

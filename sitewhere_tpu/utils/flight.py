@@ -46,37 +46,10 @@ STAGE_ORDER = ("decode", "arena_fill", "wal_append", "commit",
 QUERY_STAGE_ORDER = ("lookup", "device", "format", "archive")
 
 
-def query_stage_durations(stages_us: dict) -> dict:
-    """Per-stage DURATIONS (ms) for one query record — the read-path
-    sibling of :func:`stage_durations`, shared by bench.py's query
-    breakdown so "device time" always means the same interval:
-
-      lookup_ms   start -> lookup (mirror sync + string->id resolution,
-                  the only part that holds the engine lock)
-      device_ms   lookup -> device (coalesce wait + fused program +
-                  result readback)
-      format_ms   device -> format (host row formatting)
-
-    Stages a record never visited yield None."""
-    def delta(a, b):
-        if a is None or b is None:
-            return None
-        return max(0.0, (b - a) / 1000.0)
-
-    return {
-        "lookup_ms": delta(0.0, stages_us.get("lookup")),
-        "device_ms": delta(stages_us.get("lookup"),
-                           stages_us.get("device")),
-        "format_ms": delta(stages_us.get("device"),
-                           stages_us.get("format")),
-    }
-
-
 def stage_durations(stages_us: dict) -> dict:
     """Per-stage DURATIONS (ms) from one record's cumulative ``stagesUs``
-    offsets — the shared harvesting rule behind bench.py's per-stage
-    breakdown and the stage-time autotuner, so both always agree on what
-    "decode time" means:
+    offsets — the harvesting rule the stage-time autotuner steers by, so
+    every reader agrees on what "decode time" means:
 
       decode_ms        start -> decode mark (the native scan)
       wal_ms           decode/arena_fill -> wal_append (framing + buffer

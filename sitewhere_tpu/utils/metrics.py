@@ -349,8 +349,8 @@ QUERY_BATCH_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0)
 
 def query_metrics(registry: MetricsRegistry | None = None) -> dict:
     """The ``swtpu_query_*`` instruments for the batched read path — one
-    definition so the engine's QueryBatcher, bench.py, and tests always
-    agree on names and bucket layouts:
+    definition so the engine's QueryBatcher and tests always agree on
+    names and bucket layouts:
 
       swtpu_query_latency_seconds   end-to-end query_events latency
                                     (lookup + coalesce wait + device +
@@ -939,8 +939,7 @@ def cluster_metrics_instruments(registry: MetricsRegistry | None
 
       swtpu_forward_hop_seconds    sender-observed cross-rank forward
                                    RPC latency, labeled by destination
-                                   rank (the forwarded-hop p99 the bench
-                                   cluster leg reports)
+                                   rank
       swtpu_cluster_scrapes_total  federated metric scrapes served
     """
     reg = registry or REGISTRY

@@ -57,8 +57,7 @@ class ShardHeatTracker:
 
     All mutation sites hold the engine lock (harvest runs under it, the
     dispatch path already does), so no lock of its own; ``enabled``
-    toggles the per-dispatch accounting (the bench overhead estimator
-    flips it per batch, the conservation-ledger discipline).
+    toggles the per-dispatch accounting.
 
     Determinism: the tracker never reads a clock — callers pass
     ``now_s`` (the engine's harvest seam defaults it to

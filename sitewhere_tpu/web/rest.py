@@ -239,7 +239,7 @@ def make_app(instance: SiteWhereTpuInstance) -> web.Application:
     async def cluster_health(request: web.Request):
         """Rank-LOCAL replication/health view (no peer fan-out, so it
         answers instantly even mid-partition) — the surface an operator
-        (or the failover gate in bench.py) polls during an outage."""
+        polls during an outage."""
         from sitewhere_tpu.parallel.replication import (
             cluster_health_payload)
 

@@ -324,6 +324,33 @@ def test_engine_spool_job_rerun_suppresses_and_cancel_accounts(tmp_path):
         + st["cancelled"]
 
 
+def test_repeat_job_over_the_same_shapes_compiles_nothing(tmp_path):
+    """A second scoring job with the same window, channels and batch
+    width reuses the window-fill and scorer programs: no family
+    compiles during it."""
+    from sitewhere_tpu.utils.devicewatch import compile_totals
+
+    eng = _engine(tmp_path)
+    for lo in range(0, 4 * CFG["store_capacity"], 16):
+        eng.ingest_json_batch([_meas(eng, f"rj-{i % 6}", 1000 + i,
+                                     {"c0": float(i % 7), "c1": 0.5})
+                               for i in range(lo, lo + 16)])
+    eng.flush()
+    mgr = AnalyticsManager(eng)
+
+    def job(name):
+        return mgr.run_job(AnalyticsJobSpec(
+            window=W, batch_devices=M, min_fill=MIN_FILL, threshold=-1e9,
+            name=name, emit=False))
+
+    first = job("rj-warm")
+    assert first["state"] == "done" and first["scored"] == 6
+    before = compile_totals()
+    again = job("rj-steady")
+    assert (again["state"], again["scored"]) == ("done", 6)
+    assert compile_totals() == before
+
+
 def test_cancel_mid_run_lands_in_cancelled_sink(tmp_path):
     """A cancel landing between device batches routes every
     planned-but-unscored window into the cancelled sink — the equation

@@ -97,6 +97,35 @@ def test_cluster_metrics_exemplar_links_to_a_resolvable_trace(tmp_path):
         _close(clusters, host)
 
 
+def test_cluster_steady_traffic_compiles_nothing(tmp_path):
+    """Once local and forwarded ingest and the per-token, fan-out and
+    time-window queries have each run on the two ranks, the same mix
+    again compiles no program on either rank: a compile in steady
+    traffic is a latency cliff the SLO histograms would record as one
+    slow frame."""
+    from sitewhere_tpu.utils.devicewatch import WATCH
+
+    clusters, host, _ = _mk_cluster(tmp_path)
+    c0, _c1 = clusters
+
+    def mix(tag):
+        toks = _ingest_both_ranks(c0, prefix=f"st{tag}")
+        for tok in (toks[0], toks[-1]):        # owned by rank 0, rank 1
+            assert c0.query_events(device_token=tok, limit=20)["total"]
+        c0.query_events(limit=20)
+        c0.query_events(since_ms=0, limit=20)
+
+    try:
+        mix(0)
+        before, excess = WATCH.compile_totals(), WATCH.excess_total()
+        mix(1)
+        mix(2)
+        assert WATCH.compile_totals() == before
+        assert WATCH.excess_total() == excess
+    finally:
+        _close(clusters, host)
+
+
 def test_slo_harvest_consumes_each_record_once(tmp_path):
     """Two consecutive scrapes must not double-count: the flight-record
     harvest marks records consumed, so the histogram's event count equals
@@ -273,8 +302,8 @@ def test_per_peer_stale_in_health_block_and_exposition(tmp_path):
 
 def test_forward_hop_histogram_observes_forwards(tmp_path):
     """Every cross-rank forward lands in swtpu_forward_hop_seconds under
-    its destination-rank label — the forwarded-hop p99 the bench cluster
-    leg reports comes straight off this series via Histogram.quantile."""
+    its destination-rank label, and the forwarded-hop p99 comes straight
+    off this series via Histogram.quantile."""
     from sitewhere_tpu.utils.metrics import cluster_metrics_instruments
 
     clusters, host, _ = _mk_cluster(tmp_path)
@@ -294,8 +323,7 @@ def test_forward_hop_histogram_observes_forwards(tmp_path):
 
 @pytest.mark.slow
 def test_open_loop_cluster_load_stress(tmp_path):
-    """Heavy cluster-load leg in miniature (slow; the full >=1e5-event
-    version is bench.py's cluster leg): open-loop mixed traffic over a
+    """Heavy cluster-load leg in miniature (slow): open-loop mixed traffic over a
     replicated 2-rank cluster with a federated scrape mid-load, then
     no-loss + SLO-plane accounting at the end."""
     from sitewhere_tpu.loadgen import (OpenLoopSpec, TenantLoad,

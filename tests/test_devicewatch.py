@@ -240,7 +240,7 @@ def test_metrics_dict_equality_across_dispatch_shapes_with_devicewatch():
 # ===================================================================
 
 def test_memory_ledger_reconciles_with_configured_capacities():
-    """The bench hard-gate's logic as a unit pin: ring-store bytes equal
+    """Ring-store bytes equal
     the eval_shape-derived size of the configured EventStore, arena-pool
     bytes equal n_arenas x a freshly built arena of the configured
     geometry."""

@@ -49,14 +49,10 @@ sys.exit(1 if failures else 0)
 def test_offline_modules_import_with_jax_blocked():
     scripts = sorted((REPO / "scripts").glob("*.py"))
     assert scripts, "scripts/ has no modules to check"
-    # the SPMD bench leg (ISSUE 16) runs as a bench.py SUBPROCESS and
-    # keeps everything above main() stdlib-only — pin that it stays in
-    # this sweep so a module-level jax import can't sneak in
-    assert any(p.name == "bench_spmd.py" for p in scripts)
     targets = [f"file={p}" for p in scripts]
     targets.append("mod=sitewhere_tpu.utils.metrics")
     # the conservation checker (ISSUE 14): offline tooling evaluates
-    # ledger documents (bench_diff, debug-bundle triage) without jax
+    # ledger documents (debug-bundle triage) without jax
     targets.append("mod=sitewhere_tpu.utils.conservation")
     # the shard heat tracker (ISSUE 18): heat/skew documents are
     # numpy + stdlib — the engine hands in plain host arrays

@@ -195,7 +195,7 @@ def test_rules_counters_export_at_scrape():
     """ISSUE 14 satellite: the cadence-dependent CEP counters
     (missed/late/oob fires) export as swtpu_rules_* at SCRAPE time —
     kept OUT of engine.metrics() (the dispatch-shape pin is asserted by
-    bench + tests/test_rules.py) but no longer invisible without the
+    tests/test_rules.py) but no longer invisible without the
     Python API. An engine with no rule set exports none of them."""
     from sitewhere_tpu.engine import Engine, EngineConfig
     from sitewhere_tpu.rules import RuleSet, RulesManager

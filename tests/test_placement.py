@@ -528,7 +528,7 @@ def test_join_and_drain_under_the_same_protocol(tmp_path):
 
 
 def test_returning_range_never_dual_applies(tmp_path):
-    """The ping-pong pin (found by the bench leg): a range moving
+    """The ping-pong pin: a range moving
     A -> B -> A must NOT dual-count at A — A's dead rows from its first
     ownership era come back live with the slot, so the return handoff's
     replay must re-ingest ONLY what A does not already hold

@@ -138,6 +138,38 @@ def test_kill_recover_reevaluation_zero_loss_zero_dup(tmp_path):
         assert got_map == want_map
 
 
+def test_kill_recover_ledger_balances_with_rules(tmp_path):
+    """The same kill/recover slice, audited: the recovered engine's
+    ledger, rules stage included, balances over the WAL replay and the
+    alerts emitted after recovery."""
+    from sitewhere_tpu.utils.conservation import (build_ledger,
+                                                  check_conservation)
+
+    events = _stream()
+    eng = _engine(tmp_path)
+    mgr = RulesManager(eng)
+    mgr.load(RuleSet.parse(RULESET), precompile=False)
+    save_engine(eng, tmp_path / "snap")
+    _feed(eng, events, 0, 36)
+    assert mgr.poll()
+    _feed(eng, events, 36, len(events))
+    eng.wal.sync()
+    eng.wal.close()
+    del eng
+
+    r2 = restore_engine(tmp_path / "snap")
+    m2 = RulesManager(r2)
+    m2.load(RuleSet.parse(RULESET), precompile=False)
+    replay_wal_into(r2, 0, tmp_path / "wal")
+    assert m2.poll()
+    r2.flush()
+    led = build_ledger(r2, m2)
+    assert not check_conservation(led), \
+        [v.to_dict() for v in check_conservation(led)]
+    rules = led["stages"]["rules"]
+    assert rules["harvested"] > 0 and rules["emitted"] > 0
+
+
 def test_standby_runs_rules_and_promotion_emits_only_the_tail():
     """A standby applies the owner's stream (alert events included, as
     the replica feed ships them) with the same rule set but emission

@@ -78,8 +78,8 @@ def _bin_mix(n=260):
         event_ts_ms=1700000000000 + i)) for i in range(n)]
 
 
-def _run(workers, min_shard=16):
-    eng = Engine(EngineConfig(**SMALL, ingest_workers=workers))
+def _engine(workers, min_shard=16, **kw):
+    eng = Engine(EngineConfig(**{**SMALL, **kw}, ingest_workers=workers))
     if eng._arena_pool is None:
         pytest.skip("native arena path unavailable")
     if workers > 1:
@@ -87,6 +87,11 @@ def _run(workers, min_shard=16):
         eng._sharder.min_shard_payloads = min_shard
     eng.epoch.base_unix_s = 1700000000.0 - 1000.0
     eng.epoch.now_ms = lambda: 12345
+    return eng
+
+
+def _run(workers, min_shard=16):
+    eng = _engine(workers, min_shard)
     eng.ingest_json_batch(_odd_mix_json())
     eng.ingest_binary_batch(_bin_mix())
     eng.flush()
@@ -133,6 +138,183 @@ def test_sharded_decode_byte_identical_three_workers():
     single = _run(1)
     sharded = _run(3)
     assert sharded._sharder.sharded_batches > 0
+    _assert_engines_identical(single, sharded)
+
+
+# --- one behaviour per case, each batch cut into 2-3 shards of the arena ---
+def _env(token, typ, request):
+    return json.dumps({"deviceToken": token, "type": typ,
+                       "request": request}).encode()
+
+
+def _meas(token, i, **extra):
+    return _env(token, "DeviceMeasurement",
+                {"name": "temp", "value": float(i % 90), **extra,
+                 "eventDate": 1700000000000 + i})
+
+
+def _case_register_mid_batch(make, tmp):
+    """RegisterDevice envelopes inside sharded chunks re-route through
+    process() during the arena commit; the measurements around them, the
+    registered devices' own later rows included, stage unchanged."""
+    eng = make()
+    pay = [_meas(f"rg-{i % 12}", i) for i in range(300)]
+    for pos, tok in ((40, "rg-admin-0"), (97, "rg-admin-1"),
+                     (230, "rg-admin-2")):
+        pay.insert(pos, _env(tok, "RegisterDevice",
+                             {"deviceTypeToken": f"type-{tok[-1]}"}))
+        pay.insert(pos + 9, _meas(tok, pos))
+    s = eng.ingest_json_batch(pay)
+    assert s["failed"] == 0 and s["staged"] == len(pay) - 3
+    eng.flush()
+    for n in range(3):
+        info = eng.get_device(f"rg-admin-{n}")
+        assert (info.device_type, info.auto_registered) == \
+            (f"type-{n}", False)
+    return eng
+
+
+def _case_locations(make, tmp):
+    """Full location rows and rows with null or missing coordinates."""
+    eng = make()
+    pay = []
+    for i in range(300):
+        req = {"latitude": 33.75 + i * 0.01, "longitude": -84.39,
+               "elevation": 300.0 + i, "eventDate": 1700000000000 + i}
+        if i % 4 == 1:
+            req["latitude"] = None
+        elif i % 4 == 2:
+            req.update(longitude=None, elevation=None)
+        elif i % 4 == 3:
+            del req["elevation"]
+        pay.append(_env(f"lo-{i % 24}", "DeviceLocation", req)
+                   if i % 3 else _meas(f"lo-{i % 24}", i))
+    eng.ingest_json_batch(pay)
+    eng.flush()
+    # lo-4 sends only full rows (i % 4 == 0); its newest is i = 292
+    (newest, *_) = eng.get_device_state("lo-4")["recent_locations"]
+    assert (newest["latitude"], newest["longitude"],
+            newest["elevation"]) == (np.float32(33.75 + 2.92),
+                                     np.float32(-84.39), 592.0)
+    return eng
+
+
+_LEVELS = ("Info", "Warning", "Error", "Critical", "Unknown", 2, None)
+
+
+def _case_alert_levels(make, tmp):
+    """Alert levels, by name, by number and absent, fold into lane 0 of
+    the row; alert types are first seen in every shard."""
+    eng = make()
+    pay = []
+    for i in range(300):
+        req = {"type": f"kind-{i % 41}", "message": "m",
+               "eventDate": 1700000000000 + i}
+        if _LEVELS[i % 7] is not None:
+            req["level"] = _LEVELS[i % 7]
+        pay.append(_env(f"al-{i % 7}", "DeviceAlert", req)
+                   if i % 2 else _meas(f"al-{i % 7}", i))
+    eng.ingest_json_batch(pay)
+    eng.flush()
+    from sitewhere_tpu.core.types import EventType
+
+    want = {"Info": 0, "Warning": 1, "Error": 2, "Critical": 3,
+            "Unknown": 0, 2: 2, None: 0}
+    for d in range(7):
+        page = eng.query_events(device_token=f"al-{d}",
+                                etype=EventType.ALERT, limit=300)
+        assert page["total"] > 0
+        assert {e["level"] for e in page["events"]} == \
+            {want[_LEVELS[d]]}
+    return eng
+
+
+def _case_alt_ids_late(make, tmp):
+    """Alternate ids first seen past the first shard (and repeated
+    across shards) resolve to the same rows."""
+    eng = make()
+    pay = [_meas(f"ai-{i % 16}", i, **({"alternateId": f"alt-{i % 70}"}
+                                        if i >= 64 else {}))
+           for i in range(300)]
+    eng.ingest_json_batch(pay)
+    eng.flush()
+    for k in (0, 33, 69):
+        page = eng.query_events(alternate_id=f"alt-{k}", limit=16)
+        assert page["total"] >= 1
+        assert {e["deviceToken"] for e in page["events"]} == \
+            {f"ai-{i % 16}" for i in range(64, 300) if i % 70 == k}
+    return eng
+
+
+def _case_wal_replay(make, tmp):
+    """The WAL holds the raw batches a sharded decode staged: replaying
+    it into a fresh engine through utils/checkpoint rebuilds the same
+    state."""
+    from sitewhere_tpu.utils.checkpoint import replay_wal_into
+
+    wal = tmp / "wal"
+    live = make(wal_dir=str(wal))
+    live.ingest_json_batch(_odd_mix_json(300))
+    live.ingest_binary_batch(_bin_mix(200))
+    live.flush()
+    live.wal.close()
+    rep = make()
+    replay_wal_into(rep, -1, wal)
+    rep.flush()
+    _assert_engines_identical(live, rep)
+    return rep
+
+
+def _case_strict_rollback(make, tmp):
+    """A strict_channels engine accepts a batch within its lanes, then
+    rejects a colliding batch whole and rolls back the names it
+    interned."""
+    from sitewhere_tpu.engine import ChannelCapacityError
+
+    eng = make(channels=3, strict_channels=True)
+    eng.ingest_json_batch([_env(f"sc-{i % 8}", "DeviceMeasurement",
+                                {"measurements": {"a": float(i),
+                                                  "b": float(i + 1)},
+                                 "eventDate": 1700000000000 + i})
+                           for i in range(200)])
+    with pytest.raises(ChannelCapacityError):
+        eng.ingest_json_batch([_env(f"sc-{i % 8}", "DeviceMeasurement",
+                                    {"measurements": {f"n{i % 5}": 1.0},
+                                     "eventDate": 1700000000000 + i})
+                               for i in range(200)])
+    eng.flush()
+    assert len(eng.channel_map.names) == 2
+    assert eng.metrics()["persisted"] == 200
+    return eng
+
+
+_CASES = {
+    "register_mid_batch": (_case_register_mid_batch, True),
+    "locations": (_case_locations, True),
+    "alert_levels": (_case_alert_levels, True),
+    "alt_ids_late": (_case_alt_ids_late, True),
+    "wal_replay": (_case_wal_replay, True),
+    # strict engines decode under the lock with the single scanner so a
+    # rejected batch can roll back; ingest_workers must not change that
+    "strict_rollback": (_case_strict_rollback, False),
+}
+
+
+@pytest.mark.parametrize("workers", [2, 3])
+@pytest.mark.parametrize("case", list(_CASES))
+def test_sharded_decode_byte_identical_cases(case, workers, tmp_path):
+    fn, shards = _CASES[case]
+
+    def run(w):
+        def make(**kw):
+            return _engine(w, **kw)
+        return fn(make, tmp_path / f"w{w}")
+
+    single = run(1)
+    sharded = run(workers)
+    if shards:
+        assert sharded._sharder.sharded_batches > 0, \
+            "sharded path never engaged — the case proved nothing"
     _assert_engines_identical(single, sharded)
 
 

@@ -220,8 +220,8 @@ def test_single_chip_ledger_has_no_spmd_stage():
 def test_heat_fingers_hot_tenant_and_hot_slot():
     """A stream where one tenant's one token carries 8x the rows: the
     (shard, tenant) heat map's hottest cell names THAT tenant and the
-    top-1 slot is THAT token's placement slot (the bench hotspot leg's
-    oracle, deterministic here via the injected clock)."""
+    top-1 slot is THAT token's placement slot (deterministic via the
+    injected clock)."""
     eng = _spmd(2)
     eng.harvest_shard_heat(now_s=0.0)                 # prime baselines
     hot_tok, n_hot = "blaze-7", 64
@@ -250,6 +250,39 @@ def test_heat_fingers_hot_tenant_and_hot_slot():
     assert payload["flow"]["perShard"] and payload["heat"]
     assert payload["slots"]["topK"][0]["slot"] == top[0][0]
     assert payload["skew"]["dispatches"] == tracker.dispatches > 0
+
+
+def test_live_heat_harvests_compile_nothing():
+    """Harvesting heat, flow and HWM between the batches of a packed-scan
+    stream compiles no program and trips no retrace once the stream's
+    shapes are warm: the plane reads counters the fused step already
+    materialized."""
+    from sitewhere_tpu.utils.devicewatch import WATCH
+
+    eng = _spmd(2, scan_chunk=2)
+    clock = iter(range(1000))
+
+    def feed(events, tenant):
+        for lo in range(0, len(events), 16):
+            eng.ingest_json_batch([_meas(t, v, ts)
+                                   for t, v, ts in events[lo:lo + 16]],
+                                  tenant=tenant)
+            eng.harvest_shard_heat(now_s=float(next(clock)))
+            eng.shard_flow()
+            eng.take_shard_staged_hwm()
+        eng.flush()
+        eng.drain()
+        eng.spmd_heat()
+
+    events = _stream(n=96)
+    feed(events[:32], "bg")
+    feed(events[:32], "hot")
+    pre, pre_excess = WATCH.compile_totals(), WATCH.excess_total()
+    feed(events[32:], "bg")
+    feed([("blaze-1", 30.0, 5_000 + i) for i in range(48)], "hot")
+    assert WATCH.compile_totals() == pre
+    assert WATCH.excess_total() == pre_excess
+    assert eng.harvest_shard_heat(now_s=float(next(clock))).dispatches > 0
 
 
 def test_staged_hwm_reset_on_scrape_sees_drained_pileup():

@@ -702,6 +702,156 @@ def test_swp_socket_byte_identical_and_conservation():
     assert any(v.equation == "wire-frames" for v in perturbed)
 
 
+async def _mqtt_clients(port, n, prefix):
+    out = []
+    for i in range(n):
+        r, w = await asyncio.open_connection("127.0.0.1", port)
+        w.write(encode_connect(f"{prefix}-{i}"))
+        await w.drain()
+        ptype, _, body = await asyncio.wait_for(read_packet(r), 10)
+        assert ptype == CONNACK and body == b"\x00\x00"
+        out.append((r, w))
+    return out
+
+
+def test_mqtt_connections_stage_copy_free_and_compile_nothing():
+    """QoS 1 frames from many live MQTT connections stage straight into
+    the arenas, with no copy-staged row, and once the engine and the
+    edge are warm they compile no program and trip no retrace."""
+    from sitewhere_tpu.utils.devicewatch import WATCH
+
+    eng = Engine(EngineConfig(**W_CFG))
+    if eng._arena_pool is None:
+        pytest.skip("native arena path unavailable")
+    eng.epoch = FixedEpoch()
+    eng.ingest_json_batch([_payload(i) for i in range(32)])
+    eng.flush()
+    seen = {}
+
+    async def publish(clients, first, per_conn):
+        async def one(k, r, w):
+            for j in range(per_conn):
+                w.write(encode_publish(
+                    "swtpu/default/events",
+                    _payload(first + k * per_conn + j), qos=1,
+                    packet_id=j + 1))
+                await w.drain()
+                ptype, _, body = await asyncio.wait_for(read_packet(r), 30)
+                assert ptype == PUBACK
+                assert int.from_bytes(body[:2], "big") == j + 1
+        await asyncio.gather(*(one(k, r, w)
+                               for k, (r, w) in enumerate(clients)))
+
+    async def run():
+        edge = WireEdge(eng, _edge_cfg(flush_rows=16,
+                                       flush_interval_s=0.005))
+        await edge.start()
+        try:
+            await publish(await _mqtt_clients(edge.mqtt_port, 2, "warm"),
+                          100, 8)
+            eng.flush()
+            seen["compiles"] = WATCH.compile_totals()
+            seen["excess"] = WATCH.excess_total()
+            seen["counters"] = dict(eng.host_counters)
+            clients = await _mqtt_clients(edge.mqtt_port, 24, "live")
+            await publish(clients, 1_000, 6)
+            eng.flush()
+            seen["peak"] = edge.snapshot()["connections_peak"]
+            for _, w in clients:
+                w.close()
+        finally:
+            await edge.stop()
+
+    asyncio.run(run())
+    assert seen["peak"] >= 24
+    assert WATCH.compile_totals() == seen["compiles"]
+    assert WATCH.excess_total() == seen["excess"]
+    hc = eng.host_counters
+    assert hc.get("staged_copy_rows", 0) == \
+        seen["counters"].get("staged_copy_rows", 0) == 0
+    assert hc["arena_rows"] - seen["counters"]["arena_rows"] == 24 * 6
+    assert eng.metrics()["persisted"] == 32 + 2 * 8 + 24 * 6
+
+
+def test_swp_kill_mid_stream_loses_no_acked_frame(tmp_path):
+    """Crash drill: SWP acks wait for the WAL's group-commit fsync, so
+    after a kill mid-stream (sockets dropped, no batcher drain) a fresh
+    engine replaying the log holds every frame a client saw acked."""
+    import time
+
+    from sitewhere_tpu.utils.checkpoint import replay_wal_into
+
+    cfg = dict(W_CFG, store_capacity=1 << 14)
+    wal = tmp_path / "wal"
+    eng = Engine(EngineConfig(**cfg, wal_dir=str(wal),
+                              wal_group_commit=True))
+    eng.epoch = FixedEpoch()
+    warm = [_payload(i) for i in range(32)]
+    eng.ingest_json_batch(warm)
+    eng.flush()
+    n_conn, per_conn = 4, 1_000
+    acks = [0] * n_conn
+
+    def frame(k, i):
+        return _payload(1_000 + k * per_conn + i)
+
+    async def run():
+        edge = WireEdge(eng, WireEdgeConfig(
+            mqtt_port=None, tcp_port=0, flush_rows=16,
+            flush_interval_s=0.002))
+        await edge.start()
+        conns = [await _swp_connect(edge.tcp_port) for _ in range(n_conn)]
+
+        async def pump(k):
+            w = conns[k][1]
+            try:
+                for i in range(per_conn):
+                    p = frame(k, i)
+                    w.write(struct.pack("!I", len(p)) + p)
+                    await w.drain()
+            except ConnectionError:
+                pass
+
+        async def reap(k):
+            r = conns[k][0]
+            try:
+                while True:
+                    code, val = await _swp_rec(r, timeout=30)
+                    if code == SWP_ACK:
+                        acks[k] = val
+            except (asyncio.IncompleteReadError, asyncio.TimeoutError,
+                    ConnectionError):
+                pass
+
+        tasks = [asyncio.ensure_future(f(k)) for f in (pump, reap)
+                 for k in range(n_conn)]
+        deadline = time.monotonic() + 60
+        while sum(acks) < 100 and time.monotonic() < deadline:
+            await asyncio.sleep(0.005)
+        acked = list(acks)          # what the clients saw before the crash
+        edge.kill()
+        for t in tasks:
+            t.cancel()
+        await asyncio.gather(*tasks, return_exceptions=True)
+        return edge, acked
+
+    edge, acked = asyncio.run(run())
+    for b in edge.batchers:       # quiesce the flusher threads
+        b.close()
+    eng.wal.close()
+    assert sum(acked) >= 100, "no ack before the kill: the drill is vacuous"
+    rec = Engine(EngineConfig(**cfg))
+    rec.epoch = FixedEpoch()
+    replay_wal_into(rec, -1, wal)
+    rec.flush()
+    st = jax.device_get(rec.state.store)
+    have = set(np.asarray(st.ts_ms)[np.asarray(st.valid)].tolist())
+    for k in range(n_conn):
+        for i in range(acked[k]):
+            ts = json.loads(frame(k, i))["request"]["eventDate"]
+            assert ts in have, f"acked frame {i} of connection {k} lost"
+
+
 # --- observability plane -----------------------------------------------------
 
 
