@@ -721,11 +721,14 @@ class EngineConfig:
                                        # ONE lax.scan program (amortizes
                                        # dispatch/transfer per chunk; adds
                                        # up to K-1 batches of latency)
-    dispatch_depth: int = 1            # outstanding device programs before
-                                       # the dispatcher waits; >1 overlaps
-                                       # host staging with device work.
-                                       # The default 1 is unmeasured on
-                                       # the chip
+    dispatch_depth: int = 2            # outstanding device programs before
+                                       # the dispatcher waits. 2 (double
+                                       # buffering) waits on the program
+                                       # BEFORE the one just dispatched,
+                                       # so the next batch's decode, WAL
+                                       # append and commit run while the
+                                       # chip runs this one; 1 waits on
+                                       # the program just dispatched
     analytics_devices: int = 0         # HBM telemetry windows for [0, M)
     analytics_window: int = 128        # W timesteps per window
     tenant_arenas: int = 1             # >1: partition the event ring into
@@ -2329,15 +2332,21 @@ class Engine(IngestHostMixin):
 
     def _enqueue_out(self, out: StepOutput, traces: list = ()) -> None:
         """Queue a step output for drain, bounding outstanding device
-        programs to ``dispatch_depth``. At the default depth 1 the wait
-        lands on the just-dispatched program; a higher depth overlaps host
-        staging with device execution (neither is measured on the chip)."""
+        programs to ``dispatch_depth``. At the default depth 2 the wait
+        lands on the program dispatched before this one, so the caller
+        returns while this one runs and the next batch is decoded, logged
+        and staged under it (double buffering; the arena pool holds
+        ``depth + 2`` arenas, so that batch finds a free one). Depth 1
+        waits on the program just dispatched: host path and step in
+        series. The ``ready`` arg of the ``step.wait`` span says whether
+        the awaited program had already finished when the wait began."""
         self._pending_outs.append(out)
         self._pending_traces.append(list(traces))
         d = max(1, self.config.dispatch_depth)
         if len(self._pending_outs) >= d:
-            with stage("step.wait", depth=d):
-                jax.block_until_ready(self._pending_outs[-d].n_persisted)
+            awaited = self._pending_outs[-d].n_persisted
+            with stage("step.wait", depth=d, ready=int(awaited.is_ready())):
+                jax.block_until_ready(awaited)
                 # the wait observed that program's completion — stamp
                 # device_ready on its batches at zero extra sync cost
                 # (overwrite: a multi-chunk batch keeps its LAST chunk)
@@ -2358,9 +2367,9 @@ class Engine(IngestHostMixin):
             if self._pending_outs:
                 # depth 0: no program may stay outstanding (the waits of
                 # _enqueue_out carry the dispatch depth)
-                with stage("step.wait", depth=0):
-                    jax.block_until_ready(
-                        self._pending_outs[-1].n_persisted)
+                last = self._pending_outs[-1].n_persisted
+                with stage("step.wait", depth=0, ready=int(last.is_ready())):
+                    jax.block_until_ready(last)
 
     def _archive_account(self, max_new_rows: int) -> None:
         """Track the upper bound of ring rows written by a dispatch; spool

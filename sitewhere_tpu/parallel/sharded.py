@@ -1232,9 +1232,9 @@ class SpmdEngine(Engine):
                    or self._arena_backlogged()):
                 self.flush_async()
             if self._pending_outs:
-                with stage("step.wait", depth=0):
-                    jax.block_until_ready(
-                        self._pending_outs[-1].n_persisted)
+                last = self._pending_outs[-1].n_persisted
+                with stage("step.wait", depth=0, ready=int(last.is_ready())):
+                    jax.block_until_ready(last)
 
     def drain(self) -> list[dict]:
         with self.lock:

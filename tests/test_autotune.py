@@ -77,9 +77,10 @@ def test_decide_hysteresis_dead_zone():
 # ---------------------------------------------------------- engine control
 def test_autotuner_adapts_from_flight_records():
     # one decode worker, whatever the host's core count: with auto
-    # (os.cpu_count()) workers the tuner first sheds workers instead
+    # (os.cpu_count()) workers the tuner first sheds workers instead;
+    # depth 1, below the default, so that deepening shows
     eng = Engine(EngineConfig(**SMALL, autotune=True, autotune_interval=4,
-                              ingest_workers=1))
+                              ingest_workers=1, dispatch_depth=1))
     assert eng._autotuner is not None
     for b in range(16):
         eng.ingest_json_batch([

@@ -12,6 +12,7 @@ reports changes.
 import glob
 
 import jax
+import numpy as np
 import pytest
 
 from sitewhere_tpu.core.events import EventBatch
@@ -65,7 +66,10 @@ def traced(tmp_path_factory):
     tmp = tmp_path_factory.mktemp("spans")
     eng = _engine(tmp, "wal")
     frames = _frames(3)
-    _drive(eng, frames[:1])      # compile outside the trace
+    # compile outside the trace and leave that program outstanding, as
+    # in a steady stream: each traced dispatch then waits on the one
+    # before it (the default dispatch depth 2)
+    eng.ingest_json_batch(frames[0])
     with jax.profiler.trace(str(tmp / "trace")):
         _drive(eng, frames[1:])
     path, = glob.glob(str(tmp / "trace" / "**" / "*.xplane.pb"),
@@ -116,6 +120,11 @@ def test_every_span_is_written_and_nested(traced):
                if s[0] == "swtpu.step.dispatch")
     assert all(s[3]["batches"] == 1 for s in spans
                if s[0] == "swtpu.wal.gate")
+    # every wait says whether its program had finished when it began;
+    # an ingest waits on the program before its own (depth 2)
+    waits = [s[3] for s in spans if s[0] == "swtpu.step.wait"]
+    assert waits and all(w["ready"] in (0, 1) for w in waits)
+    assert all(w["depth"] in (0, 2) for w in waits)
 
 
 def test_flight_stages_fall_inside_their_spans(traced):
@@ -157,6 +166,70 @@ def test_no_profiler_changes_nothing_reported(tmp_path, traced):
                                       "commit", "wal_durable", "dispatch",
                                       "device_ready", "readback"}
     eng.wal.close()
+
+
+class _Ticket:
+    """A step output that reports not ready until it is waited on or
+    ``ready`` is set (the arena tests' stub ticket), holding the real
+    array for the readback."""
+
+    def __init__(self, arr):
+        self.arr, self.ready, self.waited = arr, False, False
+
+    def is_ready(self):
+        return self.ready
+
+    def block_until_ready(self):
+        self.ready = self.waited = True
+        self.arr.block_until_ready()
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.arr, dtype)
+
+
+def test_default_depth_waits_on_the_program_before(tmp_path):
+    """At the default depth an ingest returns with the program it just
+    dispatched still outstanding, and its dispatch waited on the program
+    before it; each ``swtpu.step.wait`` says whether that program had
+    finished when the wait began (``ready``)."""
+    from jax.profiler import ProfileData
+
+    eng = Engine(EngineConfig(**CFG))
+    assert eng.config.dispatch_depth == 2
+    real_step, tickets = eng._step, []
+
+    def step(state, batch):
+        state, out = real_step(state, batch)
+        tickets.append(_Ticket(out.n_persisted))
+        return state, out._replace(n_persisted=tickets[-1])
+
+    eng._step = step
+    frames = _frames(3)
+    eng.ingest_json_batch(frames[0])         # compiles outside the trace
+    assert len(tickets) == 1 and not tickets[0].waited
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        eng.ingest_json_batch(frames[1])
+        # waited on the program before, returned with its own running
+        assert tickets[0].waited and not tickets[1].waited
+        tickets[1].ready = True              # the chip finished it
+        eng.ingest_json_batch(frames[2])
+        assert not tickets[2].waited
+        eng.barrier()                        # waits on the newest
+    assert all(t.waited for t in tickets)
+    assert len(eng._pending_outs) == 3
+    path, = glob.glob(str(tmp_path / "trace" / "**" / "*.xplane.pb"),
+                      recursive=True)
+    spans = sorted(((ev.start_ns, ev.name, dict(ev.stats))
+                    for p in ProfileData.from_file(path).planes
+                    if p.name.startswith("/host:")
+                    for ln in p.lines for ev in ln.events
+                    if ev.name == "swtpu.step.wait"), key=lambda s: s[0])
+    assert [(s[2]["depth"], s[2]["ready"]) for s in spans] == \
+        [(2, 0), (2, 1), (0, 0)]
+    # the readback drains all three, through the stub outputs
+    assert eng.flush()["persisted"] == 3 * FRAME
+    assert not eng._pending_outs and eng.metrics()["persisted"] == 3 * FRAME
 
 
 def test_stage_marks_the_bound_record_and_keeps_the_histogram():
@@ -222,7 +295,9 @@ def traced_spmd(tmp_path_factory):
                               wal_dir=str(tmp / "wal")))
     eng.epoch.now_ms = lambda: 4242
     frames = [sum(fr, []) for fr in zip(*[iter(_frames(8))] * 2)]
-    _drive(eng, frames[:1])      # compile outside the trace
+    # compile outside the trace, leaving the program outstanding
+    eng.ingest_json_batch(frames[0])
+    eng.flush_async()
     with jax.profiler.trace(str(tmp / "trace")):
         for fr in frames[1:]:
             eng.ingest_json_batch(fr)
@@ -254,6 +329,8 @@ def test_spmd_path_writes_the_spans(traced_spmd):
                    for p in spans if p[0] == parent), child
     assert any(s[0] == "swtpu.step.wait" and s[3]["depth"] == 0
                for s in spans)
+    assert all(s[3]["ready"] in (0, 1) for s in spans
+               if s[0] == "swtpu.step.wait")
     # a batch's route passes stage all its rows, between commit's bounds
     for root in roots:
         commit, = [s for s in spans if s[0] == "swtpu.ingest.commit"
